@@ -76,6 +76,9 @@ def run(full: bool = False, engine: str = "vector"):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     eng = "des" if "--engine=des" in sys.argv or "des" in sys.argv else "vector"
     print_rows(run(full="--full" in sys.argv, engine=eng))

@@ -79,9 +79,7 @@ def run(full: bool = False):
     # oracle (the CPU hot path) and check the Pallas kernel bodies in
     # interpret mode — both chains are sequential, so the figure of
     # merit is rows/sec of queue swept, not FLOPs
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         B, J = (30, 512) if full else (30, 64)
         Ps = jnp.asarray(rng.lognormal(0.0, 0.6, (B, J)))
         th = jnp.asarray(rng.uniform(0.0, 0.5 * J, (B, J)) * float(Ps.mean()))
@@ -132,6 +130,9 @@ def run(full: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import json
     import sys
     rows = run(full="--full" in sys.argv)

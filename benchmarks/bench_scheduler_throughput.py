@@ -80,9 +80,11 @@ import os
 import sys
 import time
 
-# shard the vector engine's scenario axis across all cores (must be set
-# before jax initializes)
-if "--one-device" not in sys.argv and "XLA_FLAGS" not in os.environ:
+# on a CPU run (JAX_PLATFORMS=cpu), shard the vector engine's scenario
+# axis across all cores (must be set before jax initializes); on an
+# accelerator the real devices shard it
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "--one-device" not in sys.argv and "XLA_FLAGS" not in os.environ):
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={os.cpu_count() or 1}")
 
@@ -591,4 +593,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -49,5 +49,8 @@ def run(full: bool = False, milp_time_s: float = 60.0, n_jobs: int = 30):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     print_rows(run(full="--full" in sys.argv))

@@ -56,6 +56,9 @@ def _ratio(spt_row, hcf_row) -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     eng = "des" if "--engine=des" in sys.argv or "des" in sys.argv else "vector"
     print_rows(run(full="--full" in sys.argv, engine=eng))

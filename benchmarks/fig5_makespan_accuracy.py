@@ -34,5 +34,8 @@ def run(full: bool = False, n_points: int = 4):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     print_rows(run(full="--full" in sys.argv))

@@ -36,5 +36,8 @@ def run(full: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     print_rows(run(full="--full" in sys.argv))

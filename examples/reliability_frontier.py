@@ -96,6 +96,9 @@ def online_full_outage():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     batch_chaos_sweep()
     serving_reliability_frontier()
     online_full_outage()

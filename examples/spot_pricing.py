@@ -72,5 +72,8 @@ def serving_spot_frontier():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     batch_pricing_sweep()
     serving_spot_frontier()

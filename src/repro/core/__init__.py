@@ -54,54 +54,56 @@ Layout (one module per concern):
 ``scheduler``
     :class:`SkedulixScheduler` — the user-facing service tying
     predictions, scheduling and execution together.
-"""
-from .arrivals import (ArrivalProcess, BatchArrivals, MMPPArrivals,
-                       PoissonArrivals, TraceArrivals, parse_arrivals,
-                       resolve_release)
-from .coldstart import (ColdStartModel, PoolTrace, as_coldstart,
-                        as_pool_trace, queue_wait_ewma)
-from .cost import (CostModel, LAMBDA_COST, PriceTrace, Provider,
-                   ProviderPortfolio, as_portfolio, demo_portfolio,
-                   diurnal_portfolio, lambda_cost, scaled_portfolio,
-                   spot_portfolio, stage_costs)
-from .dag import APPS, AppDAG, Stage, image_app, matrix_app, video_app
-from .faults import FaultModel, RetryPolicy, as_fault_model
-from .greedy import (acd_sweep, acd_sweep_jax, init_offload, init_offload_jax,
-                     offload_negative_acd, select_provider,
-                     select_provider_jax, t_max)
-from .milp import MilpResult, johnson_makespan, knapsack_lower_bound, solve_milp
-from .perfmodel import (AppPerfModel, RidgeModel, StageModels, fit_app_perf_model,
-                        fit_ridge, grid_search_ridge, mape)
-from .priority import ORDERS, hcf_key, sort_queue, spt_key
-from .scheduler import BatchReport, SkedulixScheduler
-from .simulator import (SimResult, simulate, simulate_all_private,
-                        simulate_all_public)
-from .vectorsim import (ENGINE_IMPLS, VectorSimResult, resolve_engine_impl,
-                        simulate_scenarios, sweep_scenarios)
-from .workloads import (AzureWorkload, load_azure_sample, parse_workload,
-                        resolve_workload)
 
-__all__ = [
-    "AppDAG", "Stage", "APPS", "matrix_app", "video_app", "image_app",
-    "CostModel", "LAMBDA_COST", "lambda_cost", "stage_costs",
-    "PriceTrace", "Provider", "ProviderPortfolio", "as_portfolio",
-    "demo_portfolio", "spot_portfolio", "diurnal_portfolio",
-    "scaled_portfolio",
-    "ArrivalProcess", "BatchArrivals", "TraceArrivals", "PoissonArrivals",
-    "MMPPArrivals", "parse_arrivals", "resolve_release",
-    "FaultModel", "RetryPolicy", "as_fault_model",
-    "ColdStartModel", "PoolTrace", "as_coldstart", "as_pool_trace",
-    "queue_wait_ewma",
-    "init_offload", "init_offload_jax", "acd_sweep", "acd_sweep_jax",
-    "offload_negative_acd", "select_provider", "select_provider_jax", "t_max",
-    "MilpResult", "solve_milp", "johnson_makespan", "knapsack_lower_bound",
-    "RidgeModel", "fit_ridge", "grid_search_ridge", "mape", "AppPerfModel",
-    "StageModels", "fit_app_perf_model",
-    "ORDERS", "spt_key", "hcf_key", "sort_queue",
-    "SkedulixScheduler", "BatchReport",
-    "SimResult", "simulate", "simulate_all_public", "simulate_all_private",
-    "VectorSimResult", "simulate_scenarios", "sweep_scenarios",
-    "ENGINE_IMPLS", "resolve_engine_impl",
-    "AzureWorkload", "parse_workload", "resolve_workload",
-    "load_azure_sample",
-]
+Names resolve lazily (PEP 562): importing one submodule, e.g.
+``repro.core.faults`` from the training stack, runs none of the others,
+so the jit engine is loaded only by code that asks for it.
+"""
+import importlib
+
+_EXPORTS = {
+    "arrivals": ("ArrivalProcess", "BatchArrivals", "MMPPArrivals",
+                 "PoissonArrivals", "TraceArrivals", "parse_arrivals",
+                 "resolve_release"),
+    "coldstart": ("ColdStartModel", "PoolTrace", "as_coldstart",
+                  "as_pool_trace", "queue_wait_ewma"),
+    "cost": ("CostModel", "LAMBDA_COST", "PriceTrace", "Provider",
+             "ProviderPortfolio", "as_portfolio", "demo_portfolio",
+             "diurnal_portfolio", "lambda_cost", "scaled_portfolio",
+             "spot_portfolio", "stage_costs"),
+    "dag": ("APPS", "AppDAG", "Stage", "image_app", "matrix_app",
+            "video_app"),
+    "faults": ("FaultModel", "RetryPolicy", "as_fault_model"),
+    "greedy": ("acd_sweep", "acd_sweep_jax", "init_offload",
+               "init_offload_jax", "offload_negative_acd", "select_provider",
+               "select_provider_jax", "t_max"),
+    "milp": ("MilpResult", "johnson_makespan", "knapsack_lower_bound",
+             "solve_milp"),
+    "perfmodel": ("AppPerfModel", "RidgeModel", "StageModels",
+                  "fit_app_perf_model", "fit_ridge", "grid_search_ridge",
+                  "mape"),
+    "priority": ("ORDERS", "hcf_key", "sort_queue", "spt_key"),
+    "scheduler": ("BatchReport", "SkedulixScheduler"),
+    "simulator": ("SimResult", "simulate", "simulate_all_private",
+                  "simulate_all_public"),
+    "vectorsim": ("ENGINE_IMPLS", "VectorSimResult", "resolve_engine_impl",
+                  "simulate_scenarios", "sweep_scenarios"),
+    "workloads": ("AzureWorkload", "load_azure_sample", "parse_workload",
+                  "resolve_workload"),
+}
+_OWNER = {name: mod for mod, names in _EXPORTS.items() for name in names}
+
+__all__ = sorted(_OWNER)
+
+
+def __getattr__(name):
+    mod = _OWNER.get(name)
+    if mod is None:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    value = getattr(importlib.import_module(f".{mod}", __name__), name)
+    globals()[name] = value
+    return value
+
+
+def __dir__():
+    return sorted(set(globals()) | set(_OWNER))

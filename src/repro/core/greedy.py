@@ -17,6 +17,21 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum along the last axis, as the engine computes it.
+
+    On CPU this is ``jnp.cumsum``, a sequential left fold that matches
+    ``np.cumsum`` in the DES bit for bit. On other backends it is a
+    log-depth ``associative_scan``: XLA:TPU lowers a float64 ``cumsum``
+    over a 4096-wide queue to a program that takes minutes to compile,
+    where the scan compiles in seconds. Float64 is emulated there with
+    float32 pairs, so neither order matches numpy's rounding on a TPU.
+    """
+    if jax.default_backend() == "cpu":
+        return jnp.cumsum(x, axis=-1)
+    return jax.lax.associative_scan(jnp.add, x, axis=x.ndim - 1)
+
+
 # -- initialization phase (Alg. 1 lines 2-10) -----------------------------
 
 def t_max(replicas: np.ndarray, c_max: float) -> float:

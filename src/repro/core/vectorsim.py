@@ -62,10 +62,12 @@ pricing), so spot-market and diurnal tariffs sweep as a
 (M_pad, I_max, J, P, S, flags). Heterogeneous applications batch into a
 single call — stages are topologically relabelled, short DAGs are padded
 with inert stages (no jobs eligible, so their event loops run zero
-iterations) — and the whole figure's scenario axis shards across host
-devices (``XLA_FLAGS=--xla_force_host_platform_device_count=<cores>`` on
-CPU). Lockstep vmap iteration then amortizes the small applications
-inside the largest one's event budget.
+iterations) — and the whole figure's scenario axis shards across the
+local devices: the chips of a TPU host, or on a CPU run
+(``JAX_PLATFORMS=cpu``) the virtual devices of
+``XLA_FLAGS=--xla_force_host_platform_device_count=<cores>``. Lockstep
+vmap iteration then amortizes the small applications inside the largest
+one's event budget.
 
 Exogenous arrivals are data too: the per-stage loop already consumes a
 general per-job arrival vector (feed-forward stages arrive whenever their
@@ -76,10 +78,12 @@ replace the scalar deadline in the ACD. No new executables: the shape
 family stays (M_pad, I_max, J, P, S, flags), and a batch (all releases
 at ``t0``) reproduces the pre-arrivals path bit-exactly.
 
-All arithmetic runs in float64 (via ``jax.experimental.enable_x64``) so
+All arithmetic runs in float64 (under ``jax.enable_x64(True)``) so
 keep/offload decisions agree bit-for-bit with the numpy DES; equivalence
 is exact for tie-free (continuous) latency draws, where the DES heap order
-and the engine's index order coincide.
+and the engine's index order coincide. On a TPU, XLA emulates float64
+with float32 pairs: decisions still match the DES, while times and costs
+round differently from numpy in their last bits.
 """
 from __future__ import annotations
 
@@ -93,7 +97,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .arrivals import ArrivalsLike, resolve_release
 from .coldstart import (ColdStartLike, ConcurrencyLike, PoolTraceLike,
@@ -103,7 +106,7 @@ from .cost import (CostModel, EGRESS_GB_PER_S, LAMBDA_COST, PriceTrace,
                    ProviderPortfolio, as_portfolio)
 from .dag import AppDAG
 from .faults import RetryPolicy, max_outage_slots, normalize_fault_axis
-from .greedy import init_offload_jax
+from .greedy import init_offload, prefix_sum
 from .priority import ORDERS
 from ..kernels import ops as _kernel_ops
 
@@ -123,7 +126,8 @@ from ..kernels import ops as _kernel_ops
 #:   "pallas" — the scan structure with the two sequential hot spots
 #:              (greedy ACD sweep, capped FIFO dispatch chain) replaced
 #:              by Pallas kernels (:mod:`repro.kernels`); interpret mode
-#:              on CPU, Mosaic on TPU.
+#:              on CPU, Mosaic on TPU, which refuses both kernels today
+#:              (the call fails with Mosaic's reason; no fallback).
 #:
 #: The built-in default is backend-aware: on a CPU backend the scalar
 #: loop twin measures faster at fig-4 scale (each scan trip touches
@@ -135,11 +139,7 @@ ENGINE_IMPLS = ("loop", "scan", "pallas")
 
 
 def _default_engine_impl() -> str:
-    try:
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - backend probe never fatal
-        backend = "cpu"
-    return "loop" if backend == "cpu" else "scan"
+    return "loop" if jax.default_backend() == "cpu" else "scan"
 
 
 def resolve_engine_impl(impl: Optional[str] = None) -> str:
@@ -222,6 +222,13 @@ class VectorSimResult:
             cold=None if self.cold is None else self.cold[s])
 
 
+def _inverse_perm(perm: jax.Array) -> jax.Array:
+    """Inverse of a permutation vector: the same integers as a stable
+    argsort of it, from one scatter instead of a sort."""
+    return jnp.zeros_like(perm).at[perm].set(
+        jnp.arange(perm.shape[-1], dtype=perm.dtype))
+
+
 @functools.lru_cache(maxsize=None)
 def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
                   include_transfers: bool, init_mode: int, adaptive: bool,
@@ -294,7 +301,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         """
         # queue coordinates: stable sort by stage key, ties by job id
         perm = jnp.argsort(keys_k, stable=True)
-        inv = jnp.argsort(perm, stable=True)
+        inv = _inverse_perm(perm)
         P_q = P_k[perm]
         rem_q = rem_k[perm]
         dur_q = dur_k[perm]
@@ -308,7 +315,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         a_elig = jnp.where(elig_q, a_q, jnp.inf)
         arr_order = jnp.argsort(a_elig, stable=True)
         arr_t = jnp.concatenate([a_elig[arr_order], jnp.full(1, jnp.inf)])
-        arr_rank = jnp.argsort(arr_order, stable=True)
+        arr_rank = _inverse_perm(arr_order)
         n_arr = elig_q.sum()
         ap0 = (elig_q & (a_q <= t0)).sum()  # t0 batch (source stages)
         # I_k is derived from the pool: count of present (finite) slots
@@ -383,7 +390,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
             # yields the first violator if any, else the queue head
             if adaptive:
                 contrib = jnp.where(q1, P_q, 0.0)
-                prefix_excl = jnp.cumsum(contrib) - contrib
+                prefix_excl = prefix_sum(contrib) - contrib
                 viol = (q1 & acd_k
                         & (prefix_excl > base_c - I_k * t_new))
                 has_viol = viol.any()
@@ -556,10 +563,10 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
                     has_viol = evict_now.any()
                 else:
                     contrib = jnp.where(q1, P_q, 0.0)
-                    prefix_excl = jnp.cumsum(contrib) - contrib
+                    prefix_excl = prefix_sum(contrib) - contrib
                     viol = q1 & acd_k & (prefix_excl > thresh)
                     vc = jnp.where(viol, P_q, 0.0)
-                    vprev = jnp.cumsum(vc) - vc
+                    vprev = prefix_sum(vc) - vc
                     evict_now = viol & (prefix_excl - vprev > thresh)
                     # conservative cascade-complete test: any violator
                     # surviving the certain-set round defers the dispatch
@@ -710,8 +717,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
 
     def run_one(P_pred, act_priv, pub_a, up_a, down_a, dgb_pred, cost_ps,
                 sel_ps, lat_ps, eg_ps, edges_ps,
-                stage_keys, job_keys, deadline, capacity, t0, release,
-                init_elig, live, A, desc, sink, pinned, inert, speed,
+                stage_keys, deadline, t0, release, init_elig, live, A, desc, sink, pinned, inert, speed,
                 clock0, *fault_args):
         if faulty:
             # scenario fault data: [J, M, A_att] failure draws + backoff
@@ -734,18 +740,11 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
                 best = jnp.maximum(best, jnp.where(A[k, v], rem_l[v], 0.0))
             rem_l[k] = P_pred[:, k] + best
 
-        if init_mode == 1:
-            # init_elig gates the non-clairvoyant variant (init_window):
-            # ineligible jobs contribute zero demand to the prefix scan
-            # and are never marked; all-True reproduces the classic path
-            # bit-exactly
-            off = init_offload_jax(
-                jnp.where(init_elig, P_pred.sum(axis=1), 0.0),
-                job_keys, capacity) & init_elig
-        elif init_mode == 2:
-            # paged runs: the capacity-prefix rule is *global* over the
-            # job axis, so the driver resolves it over the full job set
-            # up front and feeds the resulting mask page by page
+        if init_mode == 2:
+            # the offload plan (a policy mask, or the capacity-prefix
+            # rule, which is *global* over the job axis) is resolved on
+            # the host over the full job set and fed in, page by page
+            # when paging
             off = init_elig & live
         else:
             off = jnp.zeros(J, dtype=bool)
@@ -878,8 +877,8 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
                 dur_pj = pub_a[:, k][None, :] * lm_pj
                 capped_p = jnp.isfinite(caps_v)
                 wu_p = wu_pub if cold else jnp.zeros(P)
-                qrank = jnp.argsort(jnp.argsort(stage_keys[:, k],
-                                                stable=True), stable=True)
+                qrank = _inverse_perm(jnp.argsort(stage_keys[:, k],
+                                                  stable=True))
                 if impl == "loop":
                     order_j = jnp.lexsort((
                         jnp.where(forced_k, iota_J, qrank),
@@ -1615,8 +1614,15 @@ class _Task:
                         key_fn(pred["P_private"][b], H, None))
         stage_keys = np.stack([uniq[(b, o, tr)][0]
                                for (b, o, _, _, _, tr, _) in self.grid])
-        job_keys = np.stack([uniq[(b, o, tr)][1]
-                             for (b, o, _, _, _, tr, _) in self.grid])
+        # the engine only sorts by the stage keys, so it gets their exact
+        # stable ranks (ties by job id, as the DES queues order them):
+        # float64 keys a few ulps apart, which HCF's summed costs produce,
+        # cannot stay apart in a TPU's float32-pair float64
+        stage_keys = np.argsort(np.argsort(stage_keys, axis=1, kind="stable"),
+                                axis=1, kind="stable")
+        # job keys and capacity feed the host-side init plan only
+        self.job_keys = np.stack([uniq[(b, o, tr)][1]
+                                  for (b, o, _, _, _, tr, _) in self.grid])
         bsel = self.batch_out
         sel_p = np.stack([sel_bt[(b, tr)]
                           for (b, _, _, _, _, tr, _) in self.grid])
@@ -1675,8 +1681,8 @@ class _Task:
                           for (_, _, _, r, g, _, _) in self.grid])
         # capacity T_max = sum_k I_k * C_max follows the scenario's own
         # replica config (raw counts, as in the DES's t_max)
-        capacity = np.array([float(repl_cfgs[r].sum()) * c
-                             for (_, _, c, r, _, _, _) in self.grid])
+        self.capacity = np.array([float(repl_cfgs[r].sum()) * c
+                                  for (_, _, c, r, _, _, _) in self.grid])
 
         # per-task scheduling-flag overrides (None = inherit the sweep's
         # init_phase/adaptive) — the policy harness mixes e.g. an
@@ -1687,7 +1693,7 @@ class _Task:
                                   else bool(adaptive_override))
         # externally-decided offload plan ([J] bool): replaces the
         # capacity-prefix rule; rides the init_mode=2 engine path (the
-        # precomputed-mask branch the paged runs already use)
+        # precomputed-plan branch the host-resolved rule also uses)
         if offload_mask is not None:
             if init_window is not None:
                 raise ValueError(
@@ -1808,9 +1814,8 @@ class _Task:
                 lat_ps,
                 eg_ps,
                 edges_ps,
-                pad_cols(stage_keys), job_keys,
+                pad_cols(stage_keys).astype(np.int32),
                 rel[None, :] + self.c_max_out[:, None],
-                capacity,
                 np.full(S, self.t0),
                 np.broadcast_to(rel, (S, self.J)),
                 np.broadcast_to(init_elig, (S, self.J)),
@@ -1827,20 +1832,40 @@ class _Task:
     # engine-arg positions carrying a job axis (position -> axis), for the
     # job pager; fault args (fail/delay grids) follow at _N_BASE_ARGS
     _PAGE_J_AXES = {0: 1, 1: 1, 2: 1, 3: 1, 4: 1, 5: 1, 6: 3, 7: 3,
-                    11: 1, 12: 1, 13: 1, 16: 1, 17: 1, 18: 1}
-    _N_BASE_ARGS = 26
-    _IDX_DEADLINE, _IDX_RELEASE = 13, 16
-    _IDX_INIT_ELIG, _IDX_LIVE, _IDX_CLOCK0 = 17, 18, 25
+                    11: 1, 12: 1, 14: 1, 15: 1, 16: 1}
+    _N_BASE_ARGS = 24
+    _IDX_DEADLINE, _IDX_RELEASE = 12, 14
+    _IDX_INIT_ELIG, _IDX_LIVE, _IDX_CLOCK0 = 15, 16, 23
 
     def eff_modes(self, init_phase: bool, adaptive: bool) -> Tuple[int, bool]:
         """(engine init_mode, adaptive) for this task under the sweep's
-        defaults: per-task overrides win, and a policy-supplied offload
-        mask compiles the precomputed-plan engine (``init_mode=2``)."""
+        defaults: per-task overrides win. Any offload plan — a
+        policy-supplied mask or the capacity-prefix rule, resolved on the
+        host by :meth:`init_plan` — compiles the precomputed-plan engine
+        (``init_mode=2``)."""
         ip = init_phase if self.init_override is None else self.init_override
         ad = adaptive if self.adaptive_override is None \
             else self.adaptive_override
-        mode = 2 if self.mask is not None else (1 if ip else 0)
+        mode = 2 if self.mask is not None or ip else 0
         return mode, bool(ad)
+
+    def init_plan(self, init_phase: bool) -> np.ndarray:
+        """The [S, J] init-offload plan the ``init_mode=2`` engine reads
+        from the ``init_elig`` slot: the policy mask, else the global
+        capacity-prefix rule in the DES's own numpy arithmetic (so its
+        decisions match the DES on every backend, and a paged run
+        reproduces a monolithic one), else all-False."""
+        if self.mask is not None:
+            return np.broadcast_to(self.mask, (self.S, self.J)).copy()
+        if self.eff_modes(init_phase, True)[0] == 2:
+            return _host_init_offload(self)
+        return np.zeros((self.S, self.J), dtype=bool)
+
+    def engine_args(self, init_phase: bool) -> tuple:
+        """The monolithic engine's arg tuple, init plan resolved."""
+        args = list(self.args)
+        args[self._IDX_INIT_ELIG] = self.init_plan(init_phase)
+        return tuple(args)
 
     def page_args(self, idx: np.ndarray, J_fam: int, init_mask: np.ndarray,
                   clocks: np.ndarray) -> tuple:
@@ -1915,7 +1940,7 @@ class _Task:
 def _dispatch(fn, args, S: int, n_dev: int) -> Dict[str, np.ndarray]:
     """Run a compiled engine over scenario-axis args, sharding across
     host devices, and return the output tree as numpy arrays."""
-    with enable_x64():
+    with jax.enable_x64(True):
         if n_dev > 1:
             # strided scenario->device interleave balances heterogeneous
             # grids across the lockstep shards
@@ -1971,16 +1996,14 @@ def _finalize(task: _Task, out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
 
 def _host_init_offload(task: _Task) -> np.ndarray:
     """Resolve the global capacity-prefix init-offload mask [S, J] on the
-    host, mirroring the in-engine computation (``init_mode=1``) op for op
-    so a paged run (``init_mode=2``) reproduces the monolithic mask."""
-    P_pred, job_keys = task.args[0], task.args[12]
-    capacity, init_elig = task.args[14], task.args[17]
-    with enable_x64():
-        fn = jax.jit(jax.vmap(
-            lambda Pp, keys, cap, elig: init_offload_jax(
-                jnp.where(elig, Pp.sum(axis=1), 0.0), keys, cap) & elig))
-        return np.asarray(fn(jnp.asarray(P_pred), jnp.asarray(job_keys),
-                             jnp.asarray(capacity), jnp.asarray(init_elig)))
+    host with the DES's numpy rule. ``init_elig`` gates the
+    non-clairvoyant variant (``init_window``): ineligible jobs contribute
+    zero demand to the prefix scan and are never marked."""
+    P_pred, init_elig = task.args[0], task.args[task._IDX_INIT_ELIG]
+    return np.stack([
+        init_offload(np.where(elig, Pp.sum(axis=1), 0.0), keys, cap) & elig
+        for Pp, keys, cap, elig in zip(P_pred, task.job_keys, task.capacity,
+                                       init_elig)])
 
 
 # most recent paged run's page/retry counts (observability hook for the
@@ -1994,8 +2017,8 @@ _LAST_RUN_STATS: Dict[str, object] = {}
 
 
 def _run_paged(task: _Task, I_max: int, include_transfers: bool,
-               init_phase: bool, adaptive: bool, lookahead: bool,
-               chunk: int, n_dev: int,
+               init_phase: bool, init_mode: int, adaptive: bool,
+               lookahead: bool, chunk: int, n_dev: int,
                impl: str = "scan") -> Dict[str, np.ndarray]:
     """Page the job axis through fixed-J compiled executables.
 
@@ -2017,16 +2040,9 @@ def _run_paged(task: _Task, I_max: int, include_transfers: bool,
     order = np.argsort(rel, kind="stable")
     rel_sorted = rel[order]
     t_plan = time.perf_counter()
-    if task.mask is not None:
-        # policy-supplied plan: already global, nothing to resolve
-        off_full = np.broadcast_to(task.mask, (S, J)).copy()
-    elif init_phase:
-        off_full = _host_init_offload(task)
-    else:
-        off_full = np.zeros((S, J), dtype=bool)
+    off_full = task.init_plan(init_phase)
     _LAST_RUN_STATS["plan_s"] = (_LAST_RUN_STATS.get("plan_s", 0.0)
                                  + time.perf_counter() - t_plan)
-    masked = init_phase or task.mask is not None
     bufs: Optional[Dict[str, np.ndarray]] = None
     clocks = task.args[task._IDX_CLOCK0]
     pos, size = 0, int(chunk)
@@ -2046,7 +2062,7 @@ def _run_paged(task: _Task, I_max: int, include_transfers: bool,
         args = task.page_args(idx, J_fam, off_full[:, idx], clocks)
         fn = _engine_fn(task.M_pad, I_max, J_fam, task.n_providers,
                         task.n_segments, include_transfers,
-                        2 if masked else 0, adaptive,
+                        init_mode, adaptive,
                         task.n_attempts, task.n_windows, task.faulty,
                         lookahead, task.capped, task.cold, task.pooled,
                         task.C, n_dev, impl)
@@ -2095,20 +2111,20 @@ def _run_task(task: _Task, I_max: int, include_transfers: bool,
     n_dev = jax.local_device_count() if S > 1 else 1
     chunked = (chunk_jobs is not None and task.release is not None
                and int(chunk_jobs) < task.J)
-    init_mode, adaptive = task.eff_modes(init_phase, adaptive)
+    init_mode, eff_adaptive = task.eff_modes(init_phase, adaptive)
     t_run = time.perf_counter()
     if chunked:
-        out = _run_paged(task, I_max, include_transfers,
-                         init_mode == 1, adaptive, lookahead,
+        out = _run_paged(task, I_max, include_transfers, init_phase,
+                         init_mode, eff_adaptive, lookahead,
                          int(chunk_jobs), n_dev, impl)
     else:
         fn = _engine_fn(task.M_pad, I_max, task.J, task.n_providers,
                         task.n_segments, include_transfers,
-                        init_mode, adaptive,
+                        init_mode, eff_adaptive,
                         task.n_attempts, task.n_windows, task.faulty,
                         lookahead, task.capped, task.cold, task.pooled,
                         task.C, n_dev, impl)
-        out = _dispatch(fn, task.args, S, n_dev)
+        out = _dispatch(fn, task.engine_args(init_phase), S, n_dev)
     t_done = time.perf_counter()
     res = task.pack(_finalize(task, out))
     _LAST_RUN_STATS.update(
@@ -2647,7 +2663,8 @@ def sweep_scenarios(
         ps = [prepped[i] for i in grp]
         p0 = ps[0]
         t_run = time.perf_counter()
-        fused = tuple(np.concatenate([p.args[k] for p in ps])
+        task_args = [p.engine_args(bool(init_phase)) for p in ps]
+        fused = tuple(np.concatenate([a[k] for a in task_args])
                       for k in range(len(p0.args)))
         grp_mode, grp_adapt = p0.eff_modes(bool(init_phase),
                                            bool(adaptive))

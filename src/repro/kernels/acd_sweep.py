@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the TPU compiler-params dataclass was renamed across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def _acd_kernel(p_ref, t_ref, m_ref, e_ref):
     J = p_ref.shape[-1]
@@ -61,7 +57,7 @@ def acd_evict(P: jax.Array, thresh: jax.Array, mask: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, J), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, J), jnp.bool_),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(P, thresh, mask)

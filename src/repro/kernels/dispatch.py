@@ -25,10 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the TPU compiler-params dataclass was renamed across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def _dispatch_kernel(order_ref, pub_ref, n_ref, ready_ref, dur_ref,
                      selc_ref, occ_ref, seg_ref, cap_ref, wu_ref,
@@ -114,8 +110,8 @@ def fifo_dispatch(order: jax.Array, locpub: jax.Array, n_pub: jax.Array,
 
     outs = pl.pallas_call(
         functools.partial(_dispatch_kernel, cold=cold),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 13,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 7,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 13,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 7,
         out_shape=[
             jax.ShapeDtypeStruct((1, J), jnp.int32),   # prov
             jax.ShapeDtypeStruct((1, J), jnp.int32),   # seg

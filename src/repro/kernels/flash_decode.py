@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the TPU compiler-params dataclass was renamed across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128
 
@@ -101,7 +97,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((g, _LANES), jnp.float32),
             pltpu.VMEM((g, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length.reshape(b, 1).astype(jnp.int32), qg, kp, vp)

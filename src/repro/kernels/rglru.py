@@ -18,10 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the TPU compiler-params dataclass was renamed across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def _rglru_kernel(x_ref, a_ref, h0_ref, y_ref, hT_ref, h_ref, *,
                   bt: int, nt: int):
@@ -79,7 +75,7 @@ def rglru(x: jax.Array, a: jax.Array, h0: jax.Array | None = None, *,
             jax.ShapeDtypeStruct((bp, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bb, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xp, ap, h0p)

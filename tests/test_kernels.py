@@ -1,4 +1,5 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret mode."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -183,9 +184,7 @@ class TestACDEvict:
 
     @pytest.mark.parametrize("b,j", [(1, 8), (4, 64), (30, 64), (3, 512)])
     def test_pallas_vs_ref_f64(self, rng, b, j):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             P = jnp.asarray(rng.lognormal(0.0, 0.6, (b, j)))
             # thresholds in the contested range so sweeps actually evict
             thresh = jnp.asarray(
@@ -197,9 +196,7 @@ class TestACDEvict:
             assert not np.asarray(got)[~np.asarray(mask)].any()
 
     def test_matches_iterated_cascade(self, rng):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             for _ in range(10):
                 j = int(rng.integers(4, 40))
                 P = rng.lognormal(0.0, 0.8, j)
@@ -249,9 +246,7 @@ class TestFIFODispatch:
     @pytest.mark.parametrize("j,p,c,n_pub", [(8, 2, 2, 8), (24, 3, 4, 17),
                                              (64, 4, 2, 50)])
     def test_pallas_vs_ref_bitexact(self, rng, cold, j, p, c, n_pub):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             args = _dispatch_inputs(rng, j, p, c, n_pub, cold)
             got = ops.fifo_dispatch(*args, cold=cold, use_pallas=True)
             want = ref.fifo_dispatch_ref(*args, cold=cold)
@@ -262,9 +257,7 @@ class TestFIFODispatch:
                 np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
     def test_chain_advances_clocks_sequentially(self, rng):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             # all jobs to one capped provider with one slot: starts must
             # chain end-to-end in visit order (pure FIFO queueing)
             J = 6
@@ -278,9 +271,7 @@ class TestFIFODispatch:
                 assert start[b] >= end[a] or np.isclose(start[b], end[a])
 
     def test_n_pub_truncates(self, rng):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             args = list(_dispatch_inputs(rng, 12, 2, 2, 12, False))
             args[2] = jnp.asarray(5, jnp.int32)            # only 5 dispatch
             got = ops.fifo_dispatch(*args, use_pallas=True)

@@ -9,10 +9,10 @@ lower bound) the MILP; and the cost-model correctness fixes
 """
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import (APPS, LAMBDA_COST, CostModel, Provider,
                         ProviderPortfolio, acd_sweep, acd_sweep_jax,
@@ -43,7 +43,7 @@ class TestMinQuantums:
 
     def test_twins_agree_on_edge_draws(self):
         t = np.array([-10.0, 0.0, 1e-9, 50.0, 100.0, 100.1, 1e5])
-        with enable_x64():
+        with jax.enable_x64(True):
             a = np.asarray(LAMBDA_COST(jnp.asarray(t), 1024.0))
         b = LAMBDA_COST.np_cost(t, 1024.0)
         np.testing.assert_array_equal(a, b)
@@ -66,7 +66,7 @@ class TestMinQuantums:
 
 class TestAcdDtype:
     def test_jnp_twin_follows_input_dtype(self):
-        with enable_x64():
+        with jax.enable_x64(True):
             out = acd_sweep_jax(jnp.asarray(np.ones(4)),
                                 jnp.asarray(np.ones(4)), 0.0, 10.0, 1)
             assert out.dtype == jnp.float64
@@ -80,7 +80,7 @@ class TestAcdDtype:
         D = 1000000.0
         ref = acd_sweep(P_q, path, t=0.0, deadline=D, replicas=1)
         assert ref[1] < 0.0  # numpy DES: evict
-        with enable_x64():
+        with jax.enable_x64(True):
             out = np.asarray(acd_sweep_jax(jnp.asarray(P_q),
                                            jnp.asarray(path), 0.0, D, 1))
         np.testing.assert_array_equal(out, ref)
@@ -116,7 +116,7 @@ class TestSelection:
         P_pub = rng.uniform(0.01, 20.0, (12, 3))
         sel = pf.np_selection_costs(P_pub, np.array([512.0, 1024.0, 2048.0]))
         a = select_provider(sel)
-        with enable_x64():
+        with jax.enable_x64(True):
             b = np.asarray(select_provider_jax(jnp.asarray(sel)))
         np.testing.assert_array_equal(a, b)
 

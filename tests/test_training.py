@@ -117,3 +117,15 @@ class TestFaultTolerance:
         guard._stop = True           # simulate SIGTERM delivery
         p, o, log = tr.fit(p, o, data.iterate(), steps=50, guard=guard)
         assert latest_step(str(tmp_path)) == 1   # stopped after 1 step, saved
+
+
+def test_fault_plumbing_does_not_load_the_engine():
+    """``training.fault`` takes ``RetryPolicy`` from ``core.faults``
+    without running the scheduler or the jit engine, so an engine import
+    error cannot fail the training stack."""
+    from ._subproc import run_py
+
+    out = run_py("import sys, repro.training.fault; "
+                 "print(sorted(m for m in sys.modules "
+                 "if m.startswith('repro.core.')))", devices=1)
+    assert out.strip() == "['repro.core.faults']"
