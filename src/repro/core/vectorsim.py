@@ -90,6 +90,7 @@ from __future__ import annotations
 import dataclasses
 from collections import OrderedDict
 import functools
+import itertools
 import os
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -293,11 +294,13 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         the stage's replica pool, ``clock0_k`` [I_max] the busy-until
         clock each present replica starts from (``t0`` for a monolithic
         run; a previous page's final clocks when paging the job axis).
-        Returns (times, replica, clocks) in job coords: ``times`` holds
-        the dispatch instant of private jobs and ``-(eviction instant)
-        - 1`` of evicted ones (NaN = never exited); ``clocks`` the final
-        per-replica busy-until vector. Placement/pricing happen in the
-        caller, where the offload epoch is known.
+        Returns (times, replica, clocks, cold, trips) in job coords:
+        ``times`` holds the dispatch instant of private jobs and
+        ``-(eviction instant) - 1`` of evicted ones (NaN = never exited);
+        ``clocks`` the final per-replica busy-until vector; ``trips`` the
+        while-loop trips this lane needed (under vmap every lane runs as
+        many as the slowest). Placement/pricing happen in the caller,
+        where the offload epoch is known.
         """
         # queue coordinates: stable sort by stage key, ties by job id
         perm = jnp.argsort(keys_k, stable=True)
@@ -694,6 +697,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
                      jnp.zeros((), bool), jnp.zeros((), jnp.int32)) + cold0
             carry = jax.lax.while_loop(cond, body, carry)
             svr, times, rep = carry[3], carry[4], carry[5]
+            trips = carry[7]
         else:
             # initial word: clean = False (sweep before first advance),
             # it = 0, queue non-empty iff the t0 batch admitted anything
@@ -708,12 +712,12 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
             carry = jax.lax.while_loop(
                 cond_batched, lambda c: body_batched(body_batched(c)),
                 carry)
-            if os.environ.get("VS_TRIPS"):
-                jax.debug.print("TRIPS {}", carry[1] >> IT_SHIFT)
             svr, times, rep = carry[2], carry[3], carry[4]
+            # IT counts body steps, two per while trip
+            trips = ((carry[1] >> IT_SHIFT) >> 1).astype(jnp.int32)
         coldq = carry[-1][inv] if cold else jnp.zeros((J,), bool)
         # back to job coordinates
-        return times[inv], rep[inv], svr, coldq
+        return times[inv], rep[inv], svr, coldq, trips
 
     def run_one(P_pred, act_priv, pub_a, up_a, down_a, dgb_pred, cost_ps,
                 sel_ps, lat_ps, eg_ps, edges_ps,
@@ -762,6 +766,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         failc_l: List[Optional[jax.Array]] = [None] * M
         qexit_l: List[Optional[jax.Array]] = [None] * M
         clocks_l: List[Optional[jax.Array]] = [None] * M
+        trips_l: List[Optional[jax.Array]] = [None] * M
         qwait_l: List[Optional[jax.Array]] = [None] * M
         coldm_l: List[Optional[jax.Array]] = [None] * M
         ab_j = jnp.zeros(J, dtype=bool)
@@ -790,7 +795,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
                 # dead jobs (abandoned upstream) never enter a queue
                 elig = elig & jnp.isfinite(a)
             acd_k = ~pinned[k]
-            times_j, rep_j, svr_k, coldq = run_stage(
+            times_j, rep_j, svr_k, coldq, trips_l[k] = run_stage(
                 k, a, forced_k, elig, speed[k], clock0[k], acd_k,
                 P_pred[:, k], rem_l[k], act_priv[:, k], stage_keys[:, k],
                 deadline, t0,
@@ -1213,14 +1218,17 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         # one. qexit (raw sign-encoded queue-exit times) and clocks (the
         # final per-replica busy-until vectors) exist for the pager: the
         # former drives the page-safety check, the latter is the carry.
+        # trips [M] (each stage's while-loop trips in this lane) feeds the
+        # host's lockstep counters.
         qexit = jnp.stack(qexit_l, axis=1)
         clocks = jnp.stack(clocks_l, axis=0)
+        trips = jnp.stack(trips_l)
         qwait = jnp.stack(qwait_l, axis=1)
         coldm = jnp.stack(coldm_l, axis=1)
         if not faulty:
             cost_j = jnp.sum(jnp.where(locpub, cost_m, 0.0), axis=1) + xeg_j
             return dict(cost_j=cost_j, init_off=off,
-                        qexit=qexit, clocks=clocks,
+                        qexit=qexit, clocks=clocks, trips=trips,
                         public_mask=locpub, start=start, end=end,
                         completion=completion,
                         provider=jnp.where(locpub, prov_m, -1),
@@ -1236,7 +1244,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         cost_j = (jnp.sum(jnp.where(locpub, cost_m, 0.0), axis=1)
                   + xeg_j + lost_j)
         return dict(cost_j=cost_j, init_off=off,
-                    qexit=qexit, clocks=clocks,
+                    qexit=qexit, clocks=clocks, trips=trips,
                     public_mask=locpub, start=start,
                     end=jnp.where(jnp.isinf(end), jnp.nan, end),
                     completion=completion_out,
@@ -1939,30 +1947,53 @@ class _Task:
 
 def _dispatch(fn, args, S: int, n_dev: int) -> Dict[str, np.ndarray]:
     """Run a compiled engine over scenario-axis args, sharding across
-    host devices, and return the output tree as numpy arrays."""
+    host devices, and return the output tree as numpy arrays.
+
+    The host side is timed in four spans (``h2d``, ``launch``, ``wait``,
+    ``d2h``); the call's bytes each way and its lanes' while-loop trips
+    are counted (see :data:`_LAST_RUN_STATS`)."""
     with jax.enable_x64(True):
-        if n_dev > 1:
-            # strided scenario->device interleave balances heterogeneous
-            # grids across the lockstep shards
-            pad = (-S) % n_dev
-            sel = np.arange(S + pad) % S
-            perm = sel.reshape(-1, n_dev).T.reshape(-1)
+        with _span("h2d"):
+            if n_dev > 1:
+                # strided scenario->device interleave balances
+                # heterogeneous grids across the lockstep shards
+                pad = (-S) % n_dev
+                sel = np.arange(S + pad) % S
+                perm = sel.reshape(-1, n_dev).T.reshape(-1)
 
-            def shard(x):
-                x = np.ascontiguousarray(x[perm])
-                return jnp.asarray(x.reshape((n_dev, -1) + x.shape[1:]))
+                def shard(x):
+                    x = np.ascontiguousarray(x[perm])
+                    return jnp.asarray(x.reshape((n_dev, -1) + x.shape[1:]))
 
-            out = fn(*[shard(a) for a in args])
-            # position of each original scenario in the device-major output
-            # (padding duplicates a few scenarios; any occurrence works)
-            pos = np.empty(S, dtype=np.int64)
-            pos[perm] = np.arange(perm.shape[0])
-            out = jax.tree_util.tree_map(
-                lambda x: np.asarray(x).reshape(
-                    (-1,) + x.shape[2:])[pos], out)
-        else:
-            out = fn(*[jnp.asarray(a) for a in args])
-            out = jax.tree_util.tree_map(np.asarray, out)
+                dev_args = [shard(a) for a in args]
+            else:
+                dev_args = [jnp.asarray(a) for a in args]
+        with _span("launch"):
+            out = fn(*dev_args)
+        with _span("wait"):
+            jax.block_until_ready(out)
+        with _span("d2h"):
+            raw = jax.tree_util.tree_map(np.asarray, out)
+            if n_dev > 1:
+                # position of each original scenario in the device-major
+                # output (padding duplicates a few scenarios; any
+                # occurrence works)
+                pos = np.empty(S, dtype=np.int64)
+                pos[perm] = np.arange(perm.shape[0])
+                out = jax.tree_util.tree_map(
+                    lambda x: x.reshape((-1,) + x.shape[2:])[pos], raw)
+            else:
+                out = raw
+    _count("engine_calls", 1)
+    _count("h2d_bytes", sum(int(x.nbytes) for x in dev_args))
+    _count("d2h_bytes", sum(int(x.nbytes) for x in raw.values()))
+    # lanes of one device run in lockstep: each stage's while loop runs
+    # as many trips as its slowest lane (padding lanes included)
+    trips = raw["trips"].reshape((-1,) + raw["trips"].shape[-2:])
+    loop = int(trips.max(axis=1).sum())
+    _count("loop_trips", loop)
+    _count("lane_trips", int(trips.sum()))
+    _count("lane_slots", trips.shape[1] * loop)
     return out
 
 
@@ -1991,6 +2022,7 @@ def _finalize(task: _Task, out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
     out["per_stage_offloads"] = locpub.sum(axis=1)
     out.pop("qexit", None)
     out.pop("clocks", None)
+    out.pop("trips", None)
     return out
 
 
@@ -2010,10 +2042,42 @@ def _host_init_offload(task: _Task) -> np.ndarray:
 # streaming tests and the throughput bench; not part of the result API)
 _LAST_PAGE_STATS: Dict[str, int] = {}
 
-# most recent sweep's wall-time split (host prep vs engine dispatch+compute
-# vs host finalize) and the engine impl that ran it — feeds the throughput
-# bench's --profile breakdown; not part of the result API
+# most recent sweep's host spans in seconds (``prep_s``, ``plan_s``,
+# ``engine_s`` and, inside it, ``h2d_s``/``launch_s``/``wait_s``/``d2h_s``,
+# ``finalize_s``), its counters (``engine_calls``, ``h2d_bytes``,
+# ``d2h_bytes``, ``loop_trips``, ``lane_trips``, ``lane_slots``) and the
+# engine impl that ran it; not part of the result API
+# (docs/architecture.md, "Reading a sweep's spans and counters")
 _LAST_RUN_STATS: Dict[str, object] = {}
+
+# id of each sweep, the one argument of its root ``vs:sweep`` annotation
+_SWEEP_IDS = itertools.count(1)
+
+
+class _span:
+    """A host phase of the current sweep: a ``vs:<name>`` annotation on
+    the profiler's host plane (on the device trace's clock) whose
+    seconds add to ``_LAST_RUN_STATS["<name>_s"]``. Always on: with no
+    trace running an annotation costs about a microsecond."""
+
+    __slots__ = ("key", "ann", "t")
+
+    def __init__(self, name: str):
+        self.key = name + "_s"
+        self.ann = jax.profiler.TraceAnnotation("vs:" + name)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t = time.perf_counter()
+
+    def __exit__(self, *exc):
+        _count(self.key, time.perf_counter() - self.t)
+        self.ann.__exit__(*exc)
+
+
+def _count(name: str, n) -> None:
+    """Add ``n`` to the current sweep's counter ``name``."""
+    _LAST_RUN_STATS[name] = _LAST_RUN_STATS.get(name, 0) + n
 
 
 def _run_paged(task: _Task, I_max: int, include_transfers: bool,
@@ -2039,10 +2103,8 @@ def _run_paged(task: _Task, I_max: int, include_transfers: bool,
     rel = task.release
     order = np.argsort(rel, kind="stable")
     rel_sorted = rel[order]
-    t_plan = time.perf_counter()
-    off_full = task.init_plan(init_phase)
-    _LAST_RUN_STATS["plan_s"] = (_LAST_RUN_STATS.get("plan_s", 0.0)
-                                 + time.perf_counter() - t_plan)
+    with _span("plan"):
+        off_full = task.init_plan(init_phase)
     bufs: Optional[Dict[str, np.ndarray]] = None
     clocks = task.args[task._IDX_CLOCK0]
     pos, size = 0, int(chunk)
@@ -2084,13 +2146,13 @@ def _run_paged(task: _Task, I_max: int, include_transfers: bool,
                                        side="right")) - pos
             n_retries += 1
             continue
+        clocks = out.pop("clocks")
+        out.pop("trips")  # [S, M]: per page, already counted
         if bufs is None:
             bufs = {name: np.empty((S, J) + v.shape[2:], dtype=v.dtype)
-                    for name, v in out.items() if name != "clocks"}
+                    for name, v in out.items()}
         for name, v in out.items():
-            if name != "clocks":
-                bufs[name][:, idx] = v[:, :n]
-        clocks = out["clocks"]
+            bufs[name][:, idx] = v[:, :n]
         pos, size = end, int(chunk)
         n_pages += 1
     assert bufs is not None
@@ -2112,26 +2174,22 @@ def _run_task(task: _Task, I_max: int, include_transfers: bool,
     chunked = (chunk_jobs is not None and task.release is not None
                and int(chunk_jobs) < task.J)
     init_mode, eff_adaptive = task.eff_modes(init_phase, adaptive)
-    t_run = time.perf_counter()
-    if chunked:
-        out = _run_paged(task, I_max, include_transfers, init_phase,
-                         init_mode, eff_adaptive, lookahead,
-                         int(chunk_jobs), n_dev, impl)
-    else:
-        fn = _engine_fn(task.M_pad, I_max, task.J, task.n_providers,
-                        task.n_segments, include_transfers,
-                        init_mode, eff_adaptive,
-                        task.n_attempts, task.n_windows, task.faulty,
-                        lookahead, task.capped, task.cold, task.pooled,
-                        task.C, n_dev, impl)
-        out = _dispatch(fn, task.engine_args(init_phase), S, n_dev)
-    t_done = time.perf_counter()
-    res = task.pack(_finalize(task, out))
-    _LAST_RUN_STATS.update(
-        impl=impl,
-        engine_s=_LAST_RUN_STATS.get("engine_s", 0.0) + (t_done - t_run),
-        finalize_s=(_LAST_RUN_STATS.get("finalize_s", 0.0)
-                    + (time.perf_counter() - t_done)))
+    with _span("engine"):
+        if chunked:
+            out = _run_paged(task, I_max, include_transfers, init_phase,
+                             init_mode, eff_adaptive, lookahead,
+                             int(chunk_jobs), n_dev, impl)
+        else:
+            fn = _engine_fn(task.M_pad, I_max, task.J, task.n_providers,
+                            task.n_segments, include_transfers,
+                            init_mode, eff_adaptive,
+                            task.n_attempts, task.n_windows, task.faulty,
+                            lookahead, task.capped, task.cold, task.pooled,
+                            task.C, n_dev, impl)
+            out = _dispatch(fn, task.engine_args(init_phase), S, n_dev)
+    with _span("finalize"):
+        res = task.pack(_finalize(task, out))
+    _LAST_RUN_STATS["impl"] = impl
     return res
 
 
@@ -2457,27 +2515,25 @@ def _prep_sweep(tasks, cost_model, include_transfers, t0, portfolio,
     # Timed into the plan_s bucket so --profile can attribute policy
     # overhead separately from generic host prep (0 on a prep-cache hit
     # — the decisions were genuinely reused).
-    t_plan = time.perf_counter()
-    prepped = [_Task(t["dag"], t["pred"], t.get("act"),
-                     t.get("c_max_grid", (60.0,)),
-                     t.get("orders", ("spt",)), cost_model, t0, M_pad,
-                     I_max=I_max, portfolio=portfolio,
-                     include_transfers=bool(include_transfers),
-                     arrivals=t.get("arrivals"),
-                     replicas=t.get("replicas"),
-                     replica_speeds=t.get("replica_speeds"),
-                     price_traces=t["price_traces"], S_seg=S_seg,
-                     faults=t.get("faults"), retry=retry_eff,
-                     init_window=t.get("init_window", init_window),
-                     A_att=A_att, W=W,
-                     caps=caps_eff, coldstart=cs, pool=t.get("_pool"),
-                     offload_mask=t.get("offload_mask"),
-                     init_override=t.get("init_phase"),
-                     adaptive_override=t.get("adaptive"),
-                     where=f"tasks[{i}]")
-               for i, t in enumerate(tasks)]
-    _LAST_RUN_STATS["plan_s"] = (_LAST_RUN_STATS.get("plan_s", 0.0)
-                                 + time.perf_counter() - t_plan)
+    with _span("plan"):
+        prepped = [_Task(t["dag"], t["pred"], t.get("act"),
+                         t.get("c_max_grid", (60.0,)),
+                         t.get("orders", ("spt",)), cost_model, t0, M_pad,
+                         I_max=I_max, portfolio=portfolio,
+                         include_transfers=bool(include_transfers),
+                         arrivals=t.get("arrivals"),
+                         replicas=t.get("replicas"),
+                         replica_speeds=t.get("replica_speeds"),
+                         price_traces=t["price_traces"], S_seg=S_seg,
+                         faults=t.get("faults"), retry=retry_eff,
+                         init_window=t.get("init_window", init_window),
+                         A_att=A_att, W=W,
+                         caps=caps_eff, coldstart=cs, pool=t.get("_pool"),
+                         offload_mask=t.get("offload_mask"),
+                         init_override=t.get("init_phase"),
+                         adaptive_override=t.get("adaptive"),
+                         where=f"tasks[{i}]")
+                   for i, t in enumerate(tasks)]
     return prepped, I_max
 
 
@@ -2569,122 +2625,122 @@ def sweep_scenarios(
         raise ValueError(f"chunk_jobs must be >= 1, got {chunk_jobs}")
     impl = resolve_engine_impl(engine_impl)
     _LAST_RUN_STATS.clear()
-    t_prep = time.perf_counter()
+    # the root span: every vs: span of this sweep nests under it
+    with jax.profiler.TraceAnnotation("vs:sweep", sweep=next(_SWEEP_IDS)):
+        with _span("prep"):
+            refs: List[object] = []
+            fp = ("v1", _prep_fp(list(tasks), refs),
+                  _prep_fp(cost_model, refs), bool(include_transfers),
+                  float(t0), _prep_fp(portfolio, refs),
+                  _prep_fp(retry, refs),
+                  None if init_window is None else float(init_window),
+                  None if chunk_jobs is None else int(chunk_jobs),
+                  _prep_fp(concurrency, refs), _prep_fp(coldstart, refs),
+                  _prep_fp(pool_trace, refs))
+            hit = _PREP_CACHE.get(fp)
+            if hit is not None:
+                _PREP_CACHE.move_to_end(fp)
+                prepped, I_max = hit[0], hit[1]
+            else:
+                prepped, I_max = _prep_sweep(
+                    tasks, cost_model, include_transfers, t0, portfolio,
+                    retry, init_window, chunk_jobs, concurrency, coldstart,
+                    pool_trace)
+                # refs pins every id-keyed object in fp for the entry's
+                # lifetime, so a reclaimed id can never alias a live key
+                _PREP_CACHE[fp] = (prepped, I_max, tuple(refs))
+                while len(_PREP_CACHE) > _PREP_CACHE_MAX:
+                    _PREP_CACHE.popitem(last=False)
 
-    refs: List[object] = []
-    fp = ("v1", _prep_fp(list(tasks), refs), _prep_fp(cost_model, refs),
-          bool(include_transfers), float(t0), _prep_fp(portfolio, refs),
-          _prep_fp(retry, refs),
-          None if init_window is None else float(init_window),
-          None if chunk_jobs is None else int(chunk_jobs),
-          _prep_fp(concurrency, refs), _prep_fp(coldstart, refs),
-          _prep_fp(pool_trace, refs))
-    hit = _PREP_CACHE.get(fp)
-    if hit is not None:
-        _PREP_CACHE.move_to_end(fp)
-        prepped, I_max = hit[0], hit[1]
-    else:
-        prepped, I_max = _prep_sweep(
-            tasks, cost_model, include_transfers, t0, portfolio, retry,
-            init_window, chunk_jobs, concurrency, coldstart, pool_trace)
-        # refs pins every id-keyed object in fp for the entry's lifetime,
-        # so a reclaimed id can never alias a live key
-        _PREP_CACHE[fp] = (prepped, I_max, tuple(refs))
-        while len(_PREP_CACHE) > _PREP_CACHE_MAX:
-            _PREP_CACHE.popitem(last=False)
-    _LAST_RUN_STATS["prep_s"] = time.perf_counter() - t_prep
+        # Call batching policy: on a multi-device host, one engine call
+        # per task, each sharding its own scenario axis — per-device state
+        # stays small (cache-resident), which measures faster than one
+        # wide fused batch. On a single device the bottleneck flips to
+        # per-call dispatch overhead, so same-shape-family tasks *fuse*:
+        # their scenario axes concatenate into one engine call (the
+        # vmapped engine is per-scenario independent, so fusion is
+        # result-invariant) and the output splits back per task. Either
+        # way tasks share compiled executables through the (M_pad, I_max,
+        # J) shape family.
+        results: List[Optional[VectorSimResult]] = [None] * len(prepped)
+        run_idx: List[int] = []
+        for i, p in enumerate(prepped):
+            if p.J == 0:
+                z2, z3 = np.zeros((p.S, 0)), np.zeros((p.S, 0, p.M))
+                results[i] = (VectorSimResult(
+                    makespan=np.zeros(p.S), cost_usd=np.zeros(p.S),
+                    public_mask=np.zeros((p.S, 0, p.M), dtype=bool),
+                    start=z3, end=z3, completion=z2,
+                    n_offloaded_stages=np.zeros(p.S, dtype=np.int64),
+                    n_init_offloaded_jobs=np.zeros(p.S, dtype=np.int64),
+                    per_stage_offloads=np.zeros((p.S, p.M),
+                                                dtype=np.int64),
+                    provider=np.full((p.S, 0, p.M), -1, dtype=np.int64),
+                    deadline=p.c_max_out.copy(), orders=p.orders_out,
+                    c_max=p.c_max_out, batch_idx=p.batch_out,
+                    release=None if p.release is None
+                    else np.zeros((p.S, 0)),
+                    replica=np.full((p.S, 0, p.M), -1, dtype=np.int64),
+                    replicas=p.repl_out.copy(),
+                    segment=np.full((p.S, 0, p.M), -1, dtype=np.int64),
+                    trace_idx=p.trace_out.copy(),
+                    attempts=np.zeros((p.S, 0, p.M), dtype=np.int64),
+                    failed=np.zeros((p.S, 0, p.M), dtype=np.int64),
+                    abandoned=np.zeros((p.S, 0), dtype=bool),
+                    fault_idx=p.fault_out.copy(),
+                    queue_wait=np.zeros((p.S, 0, p.M)),
+                    cold=np.zeros((p.S, 0, p.M), dtype=bool)))
+            else:
+                run_idx.append(i)
 
-    # Call batching policy: on a multi-device host, one engine call per
-    # task, each sharding its own scenario axis — per-device state stays
-    # small (cache-resident), which measures faster than one wide fused
-    # batch. On a single device the bottleneck flips to per-call dispatch
-    # overhead, so same-shape-family tasks *fuse*: their scenario axes
-    # concatenate into one engine call (the vmapped engine is
-    # per-scenario independent, so fusion is result-invariant) and the
-    # output splits back per task. Either way tasks share compiled
-    # executables through the (M_pad, I_max, J) shape family.
-    results: List[Optional[VectorSimResult]] = [None] * len(prepped)
-    run_idx: List[int] = []
-    for i, p in enumerate(prepped):
-        if p.J == 0:
-            z2, z3 = np.zeros((p.S, 0)), np.zeros((p.S, 0, p.M))
-            results[i] = (VectorSimResult(
-                makespan=np.zeros(p.S), cost_usd=np.zeros(p.S),
-                public_mask=np.zeros((p.S, 0, p.M), dtype=bool),
-                start=z3, end=z3, completion=z2,
-                n_offloaded_stages=np.zeros(p.S, dtype=np.int64),
-                n_init_offloaded_jobs=np.zeros(p.S, dtype=np.int64),
-                per_stage_offloads=np.zeros((p.S, p.M), dtype=np.int64),
-                provider=np.full((p.S, 0, p.M), -1, dtype=np.int64),
-                deadline=p.c_max_out.copy(), orders=p.orders_out,
-                c_max=p.c_max_out, batch_idx=p.batch_out,
-                release=None if p.release is None
-                else np.zeros((p.S, 0)),
-                replica=np.full((p.S, 0, p.M), -1, dtype=np.int64),
-                replicas=p.repl_out.copy(),
-                segment=np.full((p.S, 0, p.M), -1, dtype=np.int64),
-                trace_idx=p.trace_out.copy(),
-                attempts=np.zeros((p.S, 0, p.M), dtype=np.int64),
-                failed=np.zeros((p.S, 0, p.M), dtype=np.int64),
-                abandoned=np.zeros((p.S, 0), dtype=bool),
-                fault_idx=p.fault_out.copy(),
-                queue_wait=np.zeros((p.S, 0, p.M)),
-                cold=np.zeros((p.S, 0, p.M), dtype=bool)))
-        else:
-            run_idx.append(i)
-
-    n_dev = jax.local_device_count()
-    groups: List[List[int]] = []
-    by_key: Dict[tuple, List[int]] = {}
-    for i in run_idx:
-        p = prepped[i]
-        paged = (chunk_jobs is not None and p.release is not None
-                 and int(chunk_jobs) < p.J)
-        if n_dev > 1 or paged:
-            groups.append([i])
-            continue
-        key = (p.J, p.faulty, p.n_providers, p.n_segments, p.n_attempts,
-               p.n_windows, p.capped, p.cold, p.pooled, p.C,
-               p.eff_modes(bool(init_phase), bool(adaptive)))
-        grp = by_key.get(key)
-        if grp is None:
-            by_key[key] = grp = []
-            groups.append(grp)
-        grp.append(i)
-    for grp in groups:
-        if len(grp) == 1:
-            p = prepped[grp[0]]
-            results[grp[0]] = _run_task(
-                p, I_max, bool(include_transfers), bool(init_phase),
-                bool(adaptive), lookahead=bool(egress_lookahead),
-                chunk_jobs=None if chunk_jobs is None else int(chunk_jobs),
-                impl=impl)
-            continue
-        ps = [prepped[i] for i in grp]
-        p0 = ps[0]
-        t_run = time.perf_counter()
-        task_args = [p.engine_args(bool(init_phase)) for p in ps]
-        fused = tuple(np.concatenate([a[k] for a in task_args])
-                      for k in range(len(p0.args)))
-        grp_mode, grp_adapt = p0.eff_modes(bool(init_phase),
-                                           bool(adaptive))
-        fn = _engine_fn(p0.M_pad, I_max, p0.J, p0.n_providers,
-                        p0.n_segments, bool(include_transfers),
-                        grp_mode, grp_adapt,
-                        p0.n_attempts, p0.n_windows, p0.faulty,
-                        bool(egress_lookahead), p0.capped, p0.cold,
-                        p0.pooled, p0.C, 1, impl)
-        out = _dispatch(fn, fused, sum(p.S for p in ps), 1)
-        t_done = time.perf_counter()
-        lo = 0
-        for i, p in zip(grp, ps):
-            sub = {k: v[lo:lo + p.S] for k, v in out.items()}
-            results[i] = p.pack(_finalize(p, sub))
-            lo += p.S
-        _LAST_RUN_STATS.update(
-            impl=impl,
-            engine_s=(_LAST_RUN_STATS.get("engine_s", 0.0)
-                      + (t_done - t_run)),
-            finalize_s=(_LAST_RUN_STATS.get("finalize_s", 0.0)
-                        + (time.perf_counter() - t_done)))
-    return results
+        n_dev = jax.local_device_count()
+        groups: List[List[int]] = []
+        by_key: Dict[tuple, List[int]] = {}
+        for i in run_idx:
+            p = prepped[i]
+            paged = (chunk_jobs is not None and p.release is not None
+                     and int(chunk_jobs) < p.J)
+            if n_dev > 1 or paged:
+                groups.append([i])
+                continue
+            key = (p.J, p.faulty, p.n_providers, p.n_segments,
+                   p.n_attempts, p.n_windows, p.capped, p.cold, p.pooled,
+                   p.C, p.eff_modes(bool(init_phase), bool(adaptive)))
+            grp = by_key.get(key)
+            if grp is None:
+                by_key[key] = grp = []
+                groups.append(grp)
+            grp.append(i)
+        for grp in groups:
+            if len(grp) == 1:
+                p = prepped[grp[0]]
+                results[grp[0]] = _run_task(
+                    p, I_max, bool(include_transfers), bool(init_phase),
+                    bool(adaptive), lookahead=bool(egress_lookahead),
+                    chunk_jobs=(None if chunk_jobs is None
+                                else int(chunk_jobs)),
+                    impl=impl)
+                continue
+            ps = [prepped[i] for i in grp]
+            p0 = ps[0]
+            with _span("engine"):
+                task_args = [p.engine_args(bool(init_phase)) for p in ps]
+                fused = tuple(np.concatenate([a[k] for a in task_args])
+                              for k in range(len(p0.args)))
+                grp_mode, grp_adapt = p0.eff_modes(bool(init_phase),
+                                                   bool(adaptive))
+                fn = _engine_fn(p0.M_pad, I_max, p0.J, p0.n_providers,
+                                p0.n_segments, bool(include_transfers),
+                                grp_mode, grp_adapt,
+                                p0.n_attempts, p0.n_windows, p0.faulty,
+                                bool(egress_lookahead), p0.capped, p0.cold,
+                                p0.pooled, p0.C, 1, impl)
+                out = _dispatch(fn, fused, sum(p.S for p in ps), 1)
+            with _span("finalize"):
+                lo = 0
+                for i, p in zip(grp, ps):
+                    sub = {k: v[lo:lo + p.S] for k, v in out.items()}
+                    results[i] = p.pack(_finalize(p, sub))
+                    lo += p.S
+            _LAST_RUN_STATS["impl"] = impl
+        return results
