@@ -1217,46 +1217,100 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         # order, so a paged run sums the exact same array as a monolithic
         # one. qexit (raw sign-encoded queue-exit times) and clocks (the
         # final per-replica busy-until vectors) exist for the pager: the
-        # former drives the page-safety check, the latter is the carry.
-        # trips [M] (each stage's while-loop trips in this lane) feeds the
-        # host's lockstep counters.
+        # former drives the page-safety check, the latter is the carry;
+        # only a paged call copies them back. trips [M] (each stage's
+        # while-loop trips in this lane) feeds the host's lockstep
+        # counters. The full output set below leaves the engine through
+        # `_emitted`: outputs the static flags fix (failed, abandoned and
+        # attempts unless faulty, queue_wait unless capped, cold unless
+        # cold) stay on the device and the host rebuilds them
+        # (`_restored`), and the index outputs leave as int32.
         qexit = jnp.stack(qexit_l, axis=1)
         clocks = jnp.stack(clocks_l, axis=0)
         trips = jnp.stack(trips_l)
         qwait = jnp.stack(qwait_l, axis=1)
         coldm = jnp.stack(coldm_l, axis=1)
+        flags = dict(faulty=faulty, capped=capped, cold=cold)
         if not faulty:
             cost_j = jnp.sum(jnp.where(locpub, cost_m, 0.0), axis=1) + xeg_j
-            return dict(cost_j=cost_j, init_off=off,
-                        qexit=qexit, clocks=clocks, trips=trips,
-                        public_mask=locpub, start=start, end=end,
-                        completion=completion,
-                        provider=jnp.where(locpub, prov_m, -1),
-                        replica=rep_m,
-                        segment=jnp.where(locpub, seg_m, -1),
-                        attempts=locpub.astype(jnp.int64),
-                        failed=jnp.zeros((J, M), dtype=jnp.int64),
-                        abandoned=jnp.zeros(J, dtype=bool),
-                        queue_wait=qwait, cold=coldm)
+            return _emitted(dict(
+                cost_j=cost_j, init_off=off,
+                qexit=qexit, clocks=clocks, trips=trips,
+                public_mask=locpub, start=start, end=end,
+                completion=completion,
+                provider=jnp.where(locpub, prov_m, -1),
+                replica=rep_m,
+                segment=jnp.where(locpub, seg_m, -1),
+                attempts=locpub.astype(jnp.int64),
+                failed=jnp.zeros((J, M), dtype=jnp.int64),
+                abandoned=jnp.zeros(J, dtype=bool),
+                queue_wait=qwait, cold=coldm), **flags)
         # abandoned jobs never complete: NaN completion, NaN stage ends
         ok_j = ~ab_j
         completion_out = jnp.where(ok_j, completion, jnp.nan)
         cost_j = (jnp.sum(jnp.where(locpub, cost_m, 0.0), axis=1)
                   + xeg_j + lost_j)
-        return dict(cost_j=cost_j, init_off=off,
-                    qexit=qexit, clocks=clocks, trips=trips,
-                    public_mask=locpub, start=start,
-                    end=jnp.where(jnp.isinf(end), jnp.nan, end),
-                    completion=completion_out,
-                    provider=jnp.where(locpub, prov_m, -1),
-                    replica=rep_m,
-                    segment=jnp.where(locpub, seg_m, -1),
-                    attempts=jnp.stack(att_l, axis=1),
-                    failed=jnp.stack(failc_l, axis=1),
-                    abandoned=ab_j,
-                    queue_wait=qwait, cold=coldm)
+        return _emitted(dict(
+            cost_j=cost_j, init_off=off,
+            qexit=qexit, clocks=clocks, trips=trips,
+            public_mask=locpub, start=start,
+            end=jnp.where(jnp.isinf(end), jnp.nan, end),
+            completion=completion_out,
+            provider=jnp.where(locpub, prov_m, -1),
+            replica=rep_m,
+            segment=jnp.where(locpub, seg_m, -1),
+            attempts=jnp.stack(att_l, axis=1),
+            failed=jnp.stack(failc_l, axis=1),
+            abandoned=ab_j,
+            queue_wait=qwait, cold=coldm), **flags)
 
     return run_one
+
+
+#: outputs that only the pager reads; a monolithic call leaves them on
+#: the device
+_CARRY_OUTPUTS = ("qexit", "clocks")
+
+#: the index outputs, which cross as int32 (they lie in [-1, bound) for
+#: bounds of a few providers, replica slots or price segments; int8
+#: copied back no faster on a v5e), and their dtypes on the host
+_INDEX_OUTPUTS = dict(provider=np.int64, replica=np.int32, segment=np.int64)
+
+
+def _emitted(out: Dict[str, jax.Array], *, faulty: bool, capped: bool,
+             cold: bool) -> Dict[str, jax.Array]:
+    """The outputs one engine lane hands the host: ``out`` less those its
+    static flags make constant (the fault counters unless ``faulty``,
+    ``queue_wait`` unless ``capped``, ``cold`` unless ``cold``; the
+    capped and cold branches run only without faults), with the index
+    outputs narrowed to int32. :func:`_restored` inverts it."""
+    out = dict(out)
+    if not faulty:
+        del out["attempts"], out["failed"], out["abandoned"]
+    if faulty or not capped:
+        del out["queue_wait"]
+    if faulty or not cold:
+        del out["cold"]
+    for name in _INDEX_OUTPUTS:
+        out[name] = out[name].astype(jnp.int32)
+    return out
+
+
+def _restored(out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """The host's side of :func:`_emitted`: the index outputs widened to
+    their result dtypes and the constant outputs rebuilt, bit for bit."""
+    mask = out["public_mask"]
+    for name, dtype in _INDEX_OUTPUTS.items():
+        out[name] = out[name].astype(dtype, copy=False)
+    if "attempts" not in out:
+        out["attempts"] = mask.astype(np.int64)
+        out["failed"] = np.zeros(mask.shape, dtype=np.int64)
+        out["abandoned"] = np.zeros(mask.shape[:2], dtype=bool)
+    if "queue_wait" not in out:
+        out["queue_wait"] = np.zeros(mask.shape)
+    if "cold" not in out:
+        out["cold"] = np.zeros(mask.shape, dtype=bool)
+    return out
 
 
 @functools.lru_cache(maxsize=None)
@@ -1945,13 +1999,17 @@ class _Task:
             cold=out["cold"][:, :, inv])
 
 
-def _dispatch(fn, args, S: int, n_dev: int) -> Dict[str, np.ndarray]:
+def _dispatch(fn, args, S: int, n_dev: int, *,
+              carry: bool = False) -> Dict[str, np.ndarray]:
     """Run a compiled engine over scenario-axis args, sharding across
-    host devices, and return the output tree as numpy arrays.
+    host devices, and return its outputs as numpy arrays, as the engine
+    emits them (:func:`_emitted`; :func:`_finalize` restores the rest).
+    The pager's outputs (``qexit``, ``clocks``) are copied back only
+    with ``carry``; every copy starts before the first is waited on.
 
     The host side is timed in four spans (``h2d``, ``launch``, ``wait``,
-    ``d2h``); the call's bytes each way and its lanes' while-loop trips
-    are counted (see :data:`_LAST_RUN_STATS`)."""
+    ``d2h``); the call's bytes each way, the arrays copied back and its
+    lanes' while-loop trips are counted (see :data:`_LAST_RUN_STATS`)."""
     with jax.enable_x64(True):
         with _span("h2d"):
             if n_dev > 1:
@@ -1973,19 +2031,24 @@ def _dispatch(fn, args, S: int, n_dev: int) -> Dict[str, np.ndarray]:
         with _span("wait"):
             jax.block_until_ready(out)
         with _span("d2h"):
-            raw = jax.tree_util.tree_map(np.asarray, out)
+            out = {k: v for k, v in out.items()
+                   if carry or k not in _CARRY_OUTPUTS}
+            for v in out.values():
+                v.copy_to_host_async()
+            raw = {k: np.asarray(v) for k, v in out.items()}
             if n_dev > 1:
                 # position of each original scenario in the device-major
                 # output (padding duplicates a few scenarios; any
                 # occurrence works)
                 pos = np.empty(S, dtype=np.int64)
                 pos[perm] = np.arange(perm.shape[0])
-                out = jax.tree_util.tree_map(
-                    lambda x: x.reshape((-1,) + x.shape[2:])[pos], raw)
+                out = {k: x.reshape((-1,) + x.shape[2:])[pos]
+                       for k, x in raw.items()}
             else:
                 out = raw
     _count("engine_calls", 1)
     _count("h2d_bytes", sum(int(x.nbytes) for x in dev_args))
+    _count("d2h_arrays", len(raw))
     _count("d2h_bytes", sum(int(x.nbytes) for x in raw.values()))
     # lanes of one device run in lockstep: each stage's while loop runs
     # as many trips as its slowest lane (padding lanes included)
@@ -1998,7 +2061,9 @@ def _dispatch(fn, args, S: int, n_dev: int) -> Dict[str, np.ndarray]:
 
 
 def _finalize(task: _Task, out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
-    """Host-side canonical reductions of the engine's per-job outputs.
+    """Host-side canonical reductions of the engine's per-job outputs,
+    once the outputs the engine left on the device are restored
+    (:func:`_restored`).
 
     Scalar fields (makespan, cost_usd, the offload counters) reduce over
     the canonical job order here rather than on-device, so a paged run —
@@ -2006,6 +2071,7 @@ def _finalize(task: _Task, out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
     bit-identical floats in bit-identical order to a monolithic run.
     """
     t0 = task.t0
+    out = _restored(out)
     comp = out["completion"]
     if task.faulty:
         ok = ~out["abandoned"]
@@ -2020,8 +2086,6 @@ def _finalize(task: _Task, out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
     out["n_offloaded_stages"] = locpub.sum(axis=(1, 2))
     out["n_init_offloaded_jobs"] = out.pop("init_off").sum(axis=1)
     out["per_stage_offloads"] = locpub.sum(axis=1)
-    out.pop("qexit", None)
-    out.pop("clocks", None)
     out.pop("trips", None)
     return out
 
@@ -2045,8 +2109,8 @@ _LAST_PAGE_STATS: Dict[str, int] = {}
 # most recent sweep's host spans in seconds (``prep_s``, ``plan_s``,
 # ``engine_s`` and, inside it, ``h2d_s``/``launch_s``/``wait_s``/``d2h_s``,
 # ``finalize_s``), its counters (``engine_calls``, ``h2d_bytes``,
-# ``d2h_bytes``, ``loop_trips``, ``lane_trips``, ``lane_slots``) and the
-# engine impl that ran it; not part of the result API
+# ``d2h_arrays``, ``d2h_bytes``, ``loop_trips``, ``lane_trips``,
+# ``lane_slots``) and the engine impl that ran it; not part of the result API
 # (docs/architecture.md, "Reading a sweep's spans and counters")
 _LAST_RUN_STATS: Dict[str, object] = {}
 
@@ -2128,8 +2192,8 @@ def _run_paged(task: _Task, I_max: int, include_transfers: bool,
                         task.n_attempts, task.n_windows, task.faulty,
                         lookahead, task.capped, task.cold, task.pooled,
                         task.C, n_dev, impl)
-        out = _dispatch(fn, args, S, n_dev)
-        qx = out["qexit"][:, :n, :]
+        out = _dispatch(fn, args, S, n_dev, carry=True)
+        qx = out.pop("qexit")[:, :n, :]
         with np.errstate(invalid="ignore"):
             exit_t = np.where(qx < -0.5, -qx - 1.0, qx)
             unsafe = bool(np.any(exit_t >= T_next))  # NaN compares False

@@ -2,16 +2,19 @@
 
 Every sweep times its host phases as ``vs:`` spans (on the profiler's
 host plane and in the record), splits each engine call into transfer,
-launch, wait and copy-back, counts the call's bytes each way, and counts
-the while-loop trips its lanes needed against those the lockstep loop
-ran. The suite pins:
+launch, wait and copy-back, counts the call's bytes each way and the
+arrays copied back, and counts the while-loop trips its lanes needed
+against those the lockstep loop ran. The suite pins:
 
 * every key in the record after a sweep on each inner-loop impl, the
-  byte counts against the shapes of what crossed, and the four
-  ``_dispatch`` spans inside ``engine_s``;
+  byte counts against the shapes of what crossed (the engine's outputs
+  at their emitted widths, less the pager's carry), the ten arrays a
+  monolithic call copies back, and the four ``_dispatch`` spans inside
+  ``engine_s``;
 * occupancy: 100% for a one-scenario call, and a hand reduction of the
   ``trips`` output for a fused call of unequal scenarios;
-* a paged stream: still exact, counted per page, ``trips`` dropped;
+* a paged stream: still exact, counted per page (each page copies the
+  carry back too), ``trips`` dropped;
 * the spans' nesting in a CPU profiler trace.
 """
 import glob
@@ -28,8 +31,16 @@ from tests.test_vectorsim import grid_for, workload
 
 SPANS = ("prep_s", "plan_s", "engine_s", "h2d_s", "launch_s", "wait_s",
          "d2h_s", "finalize_s")
-COUNTERS = ("engine_calls", "h2d_bytes", "d2h_bytes", "loop_trips",
-            "lane_trips", "lane_slots")
+COUNTERS = ("engine_calls", "h2d_bytes", "d2h_arrays", "d2h_bytes",
+            "loop_trips", "lane_trips", "lane_slots")
+#: what a monolithic call of a fault-free, uncapped, warm engine copies
+#: back: the index outputs as int32, the pager's carry and the outputs
+#: its flags make constant left on the device
+MONOLITHIC = {"start": np.float64, "end": np.float64,
+              "completion": np.float64, "cost_j": np.float64,
+              "public_mask": np.bool_, "init_off": np.bool_,
+              "trips": np.int32, "provider": np.int32, "replica": np.int32,
+              "segment": np.int32}
 
 
 def _nbytes(arrays):
@@ -37,16 +48,22 @@ def _nbytes(arrays):
                for a in arrays)
 
 
+def _emitted_shapes(fn, args):
+    """Shapes and dtypes of everything the compiled engine emits."""
+    with jax.enable_x64(True):
+        return jax.eval_shape(fn, *args)
+
+
 @pytest.fixture
 def calls(monkeypatch):
-    """(args, outputs) of every engine call, outputs as `_dispatch`
-    returned them."""
+    """(args, outputs, emitted) of every engine call: outputs as
+    `_dispatch` returned them, emitted the engine's own output shapes."""
     seen = []
     orig = vectorsim._dispatch
 
-    def spy(fn, args, S, n_dev):
-        out = orig(fn, args, S, n_dev)
-        seen.append((args, dict(out)))
+    def spy(fn, args, S, n_dev, **kw):
+        out = orig(fn, args, S, n_dev, **kw)
+        seen.append((args, dict(out), _emitted_shapes(fn, args)))
         return out
 
     monkeypatch.setattr(vectorsim, "_dispatch", spy)
@@ -82,10 +99,15 @@ def test_record_holds_every_span_and_counter(impl, calls):
     assert (st["h2d_s"] + st["launch_s"] + st["wait_s"] + st["d2h_s"]
             <= st["engine_s"])
     assert st["plan_s"] <= st["prep_s"]
-    (args, out), = calls
+    (args, out, emitted), = calls
     assert st["engine_calls"] == 1
     assert st["h2d_bytes"] == _nbytes(args)
-    assert st["d2h_bytes"] == _nbytes(out.values())
+    copied = {k: v for k, v in emitted.items()
+              if k not in ("qexit", "clocks")}
+    assert st["d2h_bytes"] == sum(v.size * v.dtype.itemsize
+                                  for v in copied.values())
+    assert st["d2h_arrays"] == len(copied) == 10
+    assert {k: v.dtype for k, v in out.items()} == MONOLITHIC
     assert out["trips"].shape == (10, 3) and out["trips"].dtype == np.int32
     assert (st["loop_trips"], st["lane_trips"], st["lane_slots"]) \
         == _lockstep(out["trips"])
@@ -96,7 +118,7 @@ def test_one_scenario_call_is_fully_occupied(calls):
     task = dict(_whatif(seed=103)[0], orders=("spt",), c_max_grid=(20.0,))
     sweep_scenarios([task])
     st = vectorsim._LAST_RUN_STATS
-    (_, out), = calls
+    (_, out, _), = calls
     assert out["trips"].shape == (1, 3)
     assert st["loop_trips"] == int(out["trips"].sum()) > 0
     assert st["lane_trips"] == st["lane_slots"]
@@ -116,7 +138,7 @@ def test_fused_call_occupancy_is_the_trips_reduced_by_hand(impl, calls):
                           c_max_grid=grid_for(dag, pred, (0.2, 0.6, 1.5))))
     sweep_scenarios(tasks, engine_impl=impl)
     st = vectorsim._LAST_RUN_STATS
-    (_, out), = calls
+    (_, out, _), = calls
     trips = out["trips"]
     assert trips.shape == (12, 4)
     assert st["engine_calls"] == 1
@@ -149,10 +171,16 @@ def test_paged_stream_is_exact_and_counted_per_page(calls, monkeypatch):
     assert pages["pages"] > 1
     assert st["engine_calls"] == len(calls) \
         == pages["pages"] + pages["retries"]
-    assert st["h2d_bytes"] == sum(_nbytes(a) for a, _ in calls)
-    assert st["d2h_bytes"] == sum(_nbytes(o.values()) for _, o in calls)
+    assert st["h2d_bytes"] == sum(_nbytes(a) for a, _, _ in calls)
+    # a page copies every output the engine emits, the carry included
+    assert st["d2h_bytes"] == sum(
+        sum(v.size * v.dtype.itemsize for v in e.values())
+        for _, _, e in calls)
+    assert st["d2h_arrays"] == sum(len(e) for _, _, e in calls) \
+        == 12 * len(calls)
+    assert all({"qexit", "clocks"} <= set(o) for _, o, _ in calls)
     assert st["loop_trips"] == sum(_lockstep(o["trips"])[0]
-                                   for _, o in calls)
+                                   for _, o, _ in calls)
     assert st["lane_trips"] <= st["lane_slots"]
     assert "plan_s" in st and "engine_s" in st
     (keys,) = finalized
