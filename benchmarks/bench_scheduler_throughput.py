@@ -270,7 +270,7 @@ def measure_azure_point(J: int, engines, chunk_jobs: int = 4096,
     process peak RSS alongside throughput; the smoke assertion requires
     it to stay under 4 GB.
     """
-    from repro.core.vectorsim import _LAST_PAGE_STATS, simulate_scenarios
+    from repro.core.vectorsim import _LAST_RUN_STATS, simulate_scenarios
 
     dag = APPS["image"]
     spec = f"azure:day={day},scale={J}"
@@ -294,7 +294,7 @@ def measure_azure_point(J: int, engines, chunk_jobs: int = 4096,
         }
         extra = ""
         if eng == "vector":
-            point["pages"] = _LAST_PAGE_STATS.get("pages")
+            point["pages"] = _LAST_RUN_STATS.get("pages")
             extra = f"  {point['pages']} pages"
         print(f"  J={J:>6} {eng:>6}: {dt:8.3f}s  "
               f"{J / dt:10.0f} jobs/s  rss {rss:7.1f} MB{extra}")

@@ -191,13 +191,13 @@ def streaming_day(n_jobs: int = 100_000, chunk: int = 4096,
         t0 = time.perf_counter()
         vec = run("vector")
         wall = time.perf_counter() - t0
-    pages = dict(vectorsim._LAST_PAGE_STATS)
+    st = dict(vectorsim._LAST_RUN_STATS)
     n_scen = 1 if one else vec.num_scenarios
     print(f"[azure] workload={spec} app=image jobs={n_jobs} "
-          f"chunk_jobs={chunk} scenarios={n_scen} pages={pages.get('pages')} "
-          f"page_retries={pages.get('retries')} compile_s={cc.seconds:.3f} "
+          f"chunk_jobs={chunk} scenarios={n_scen} pages={st.get('pages')} "
+          f"carried_jobs={st.get('carried_jobs')} compile_s={cc.seconds:.3f} "
           f"wall_s={wall:.3f}")
-    report_lanes("azure", [n_scen] * (pages["pages"] + pages["retries"]))
+    report_lanes("azure", [n_scen] * st["engine_calls"])
     t0 = time.perf_counter()
     des = run("des")
     bad = compare(vec, des, f"{n_scen} scenario(s)")

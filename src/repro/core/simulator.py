@@ -1030,11 +1030,11 @@ def simulate(
     into the event heap in windows of at least ``chunk_jobs`` jobs (the
     heap holds the active window instead of the whole horizon); the
     vector engine pages jobs through fixed-shape chunks in release order
-    (compile cache keyed on the chunk family, not total J) with
-    per-replica clocks carried across pages. Results are equivalent to
-    the monolithic path on tie-free draws (bit-exact per page when no
-    page's work overlaps the next page's releases — the engine verifies
-    this and falls back to larger pages otherwise). ``egress_lookahead``
+    (compile cache keyed on the chunk family, not total J), carrying
+    the jobs still queued at each cut and the per-replica clocks into
+    the next page. Results are equivalent to the monolithic path on
+    tie-free draws (the vector engine's pages are bit-exact against its
+    monolithic run). ``egress_lookahead``
     adds a one-edge downstream-egress recourse term to the placement
     argmin (see ``_Sim._selc_at``), identically in both engines.
 

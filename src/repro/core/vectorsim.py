@@ -89,6 +89,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict
+from collections.abc import Mapping
 import functools
 import itertools
 import os
@@ -236,7 +237,7 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
                   A_att: int = 0, W: int = 0, faulty: bool = False,
                   lookahead: bool = False, capped: bool = False,
                   cold: bool = False, pooled: bool = False, C: int = 0,
-                  impl: str = "scan"):
+                  impl: str = "scan", carry: bool = False):
     """Trace the stage-decomposed event loop for one (stage count, replica
     bound, job count, provider count, price-segment count, flags) shape
     family. DAG structure arrives as data: ``A``/``desc`` are [M, M]
@@ -280,12 +281,24 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
     ``_private_done`` events still fire for draining slots). All three
     are build flags: a degenerate config compiles the pre-change graph,
     so uncapped / zero-penalty / constant-pool runs stay bit-exact.
+
+    ``carry`` builds a page of a paged stream (:func:`_run_paged`): one
+    more argument, ``fixed`` [J, M, 2], holds the queue exit and replica
+    of every (job, stage) an earlier page already decided (NaN exit = not
+    fixed). A fixed stage stays out of its queue and keeps its decision;
+    everything derived from decisions (arrivals, placements, times,
+    cost) is recomputed from it bit for bit. Each stage's event loop
+    resumes at the page's cut rather than starting there: jobs that
+    reached a queue before it are already queued, and no sweep runs at
+    the cut unless an event falls on it. The ``t0`` argument holds
+    ``[cut, next cut]``; the page emits its queue exits (``qexit``) and
+    each replica's clock at the next cut (``clocks``).
     """
     loaded = capped or cold or pooled
     iota_J = jnp.arange(J)
 
     def run_stage(k, a, forced_k, elig, speed_k, clock0_k, acd_k, P_k,
-                  rem_k, dur_k, keys_k, deadline, t0,
+                  rem_k, dur_k, keys_k, deadline, t0, t_cut=None,
                   off_k=None, csd=None):
         """Run stage k's event loop given per-job arrival times ``a`` [J].
 
@@ -293,11 +306,12 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         a constant vector for batch workloads). ``speed_k`` [I_max] holds
         the stage's replica pool, ``clock0_k`` [I_max] the busy-until
         clock each present replica starts from (``t0`` for a monolithic
-        run; a previous page's final clocks when paging the job axis).
+        run; the clocks at the page's cut when paging the job axis).
         Returns (times, replica, clocks, cold, trips) in job coords:
         ``times`` holds the dispatch instant of private jobs and
         ``-(eviction instant) - 1`` of evicted ones (NaN = never exited);
-        ``clocks`` the final per-replica busy-until vector; ``trips`` the
+        ``clocks`` the per-replica busy-until vector, final or (when
+        paging) at the next cut ``t_cut``; ``trips`` the
         while-loop trips this lane needed (under vmap every lane runs as
         many as the slowest). Placement/pricing happen in the caller,
         where the offload epoch is known.
@@ -320,7 +334,11 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         arr_t = jnp.concatenate([a_elig[arr_order], jnp.full(1, jnp.inf)])
         arr_rank = _inverse_perm(arr_order)
         n_arr = elig_q.sum()
-        ap0 = (elig_q & (a_q <= t0)).sum()  # t0 batch (source stages)
+        if carry:
+            # a page resumes at its cut: what arrived before it is queued
+            ap0 = (elig_q & (a_q < t0)).sum()
+        else:
+            ap0 = (elig_q & (a_q <= t0)).sum()  # t0 batch (source stages)
         # I_k is derived from the pool: count of present (finite) slots
         I_k = jnp.isfinite(speed_k).sum().astype(jnp.float64)
         slack_c = I_k * dl_q  # hoisted per-job term of the ACD slack
@@ -692,30 +710,47 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         times0 = jnp.full((J,), jnp.nan)
         rep0 = jnp.full((J,), -1, jnp.int32)
         t0f = jnp.asarray(t0, jnp.float64)
+        i_svr = 3 if impl == "loop" else 2  # position of svr in the carry
+
+        def at_cut(step):
+            """A page also tracks each replica's clock as it stands after
+            the last step before ``t_cut``: the clocks the next page
+            resumes from, as the loop itself computed them."""
+            def f(c):
+                c1 = step(c[:-1])
+                return c1 + (jnp.where(c1[0] < t_cut, c1[i_svr], c[-1]),)
+            return f
+
+        cut0 = (svr0,) if carry else ()
+        # a fresh loop sweeps its t0 batch before the first advance; a
+        # resumed page's queue was swept at its last event, so it is clean
+        clean0 = carry
         if impl == "loop":
-            carry = (t0f, ap0, jnp.zeros((J,), bool), svr0, times0, rep0,
-                     jnp.zeros((), bool), jnp.zeros((), jnp.int32)) + cold0
-            carry = jax.lax.while_loop(cond, body, carry)
-            svr, times, rep = carry[3], carry[4], carry[5]
-            trips = carry[7]
+            c = (t0f, ap0, jnp.zeros((J,), bool), svr0, times0, rep0,
+                 jnp.asarray(clean0), jnp.zeros((), jnp.int32)) + cold0
+            c = jax.lax.while_loop(cond, at_cut(body) if carry else body,
+                                   c + cut0)
+            trips = c[7]
         else:
-            # initial word: clean = False (sweep before first advance),
-            # it = 0, queue non-empty iff the t0 batch admitted anything
+            # initial word: the clean flag, it = 0, queue non-empty iff
+            # the t0 batch admitted anything
             st0 = (ap0.astype(jnp.int64)
+                   | (int(clean0) << CLEAN_SHIFT)
                    | ((ap0 > 0).astype(jnp.int64) << NQ_SHIFT))
-            carry = (t0f, st0, svr0, times0, rep0) + cold0
+            c = (t0f, st0, svr0, times0, rep0) + cold0
+            step = at_cut(body_batched) if carry else body_batched
             # two body steps per while trip: the guard, carry select and
             # inter-trip copies amortize over both, and XLA fuses the
             # first step's tail into the second's head. Exact because a
             # finished lane's body is a fixed point (empty queue commits
             # nothing), so the odd extra step is a no-op.
-            carry = jax.lax.while_loop(
-                cond_batched, lambda c: body_batched(body_batched(c)),
-                carry)
-            svr, times, rep = carry[2], carry[3], carry[4]
+            c = jax.lax.while_loop(cond_batched, lambda c: step(step(c)),
+                                   c + cut0)
             # IT counts body steps, two per while trip
-            trips = ((carry[1] >> IT_SHIFT) >> 1).astype(jnp.int32)
-        coldq = carry[-1][inv] if cold else jnp.zeros((J,), bool)
+            trips = ((c[1] >> IT_SHIFT) >> 1).astype(jnp.int32)
+        times, rep = c[i_svr + 1], c[i_svr + 2]
+        svr = c[-1] if carry else c[i_svr]
+        coldq = c[len(c) - 1 - carry][inv] if cold else jnp.zeros((J,), bool)
         # back to job coordinates
         return times[inv], rep[inv], svr, coldq, trips
 
@@ -723,6 +758,13 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
                 sel_ps, lat_ps, eg_ps, edges_ps,
                 stage_keys, deadline, t0, release, init_elig, live, A, desc, sink, pinned, inert, speed,
                 clock0, *fault_args):
+        if carry:
+            # a page spans [t0, t_cut): it resumes at its own cut and
+            # hands the next page the clocks at the next one
+            *fault_args, fixed = fault_args
+            t0, t_cut = t0[0], t0[1]
+        else:
+            t_cut = None
         if faulty:
             # scenario fault data: [J, M, A_att] failure draws + backoff
             # delays, [P, W, 2] outage windows, and scalar knobs
@@ -794,15 +836,22 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
             if faulty:
                 # dead jobs (abandoned upstream) never enter a queue
                 elig = elig & jnp.isfinite(a)
+            if carry:
+                # a stage an earlier page decided stays out of the queue
+                fixed_k = ~jnp.isnan(fixed[:, k, 0])
+                elig = elig & ~fixed_k
             acd_k = ~pinned[k]
-            times_j, rep_j, svr_k, coldq, trips_l[k] = run_stage(
+            times_j, rep_j, clocks_l[k], coldq, trips_l[k] = run_stage(
                 k, a, forced_k, elig, speed[k], clock0[k], acd_k,
                 P_pred[:, k], rem_l[k], act_priv[:, k], stage_keys[:, k],
-                deadline, t0,
+                deadline, t0, t_cut,
                 off_k=off_pool[k] if pooled else None,
                 csd=csd if cold else None)
+            if carry:
+                times_j = jnp.where(fixed_k, fixed[:, k, 0], times_j)
+                rep_j = jnp.where(fixed_k, fixed[:, k, 1].astype(rep_j.dtype),
+                                  rep_j)
             qexit_l[k] = times_j
-            clocks_l[k] = svr_k
             evicted = times_j < -0.5  # NaN (never exited) compares False
             locpub = forced_k | evicted
             # decision-epoch pricing: the offload epoch is the stage's
@@ -1215,61 +1264,42 @@ def _build_engine(M: int, I_max: int, J: int, P: int, S: int,
         # stage loop above); the scalar totals — makespan, cost_usd, the
         # offload counters — reduce on the *host* over canonical job
         # order, so a paged run sums the exact same array as a monolithic
-        # one. qexit (raw sign-encoded queue-exit times) and clocks (the
-        # final per-replica busy-until vectors) exist for the pager: the
-        # former drives the page-safety check, the latter is the carry;
-        # only a paged call copies them back. trips [M] (each stage's
-        # while-loop trips in this lane) feeds the host's lockstep
-        # counters. The full output set below leaves the engine through
-        # `_emitted`: outputs the static flags fix (failed, abandoned and
-        # attempts unless faulty, queue_wait unless capped, cold unless
-        # cold) stay on the device and the host rebuilds them
-        # (`_restored`), and the index outputs leave as int32.
-        qexit = jnp.stack(qexit_l, axis=1)
-        clocks = jnp.stack(clocks_l, axis=0)
-        trips = jnp.stack(trips_l)
-        qwait = jnp.stack(qwait_l, axis=1)
-        coldm = jnp.stack(coldm_l, axis=1)
-        flags = dict(faulty=faulty, capped=capped, cold=cold)
+        # one. trips [M] (each stage's while-loop trips in this lane)
+        # feeds the host's lockstep counters; a page adds qexit (raw
+        # sign-encoded queue-exit times), from which the pager decides
+        # which jobs its cut commits, and the replica clocks at the cut
+        # [M, I_max], from which the next page resumes. The output set
+        # leaves the engine through `_emitted`: outputs the static flags
+        # fix (failed, abandoned and attempts unless faulty, queue_wait
+        # unless capped, cold unless cold) stay on the device and the host
+        # rebuilds them (`_restored`), and the index outputs leave as
+        # int32.
+        out = dict(
+            init_off=off, trips=jnp.stack(trips_l), public_mask=locpub,
+            start=start, provider=jnp.where(locpub, prov_m, -1),
+            replica=rep_m, segment=jnp.where(locpub, seg_m, -1),
+            queue_wait=jnp.stack(qwait_l, axis=1),
+            cold=jnp.stack(coldm_l, axis=1))
+        if carry:
+            out.update(qexit=jnp.stack(qexit_l, axis=1),
+                       clocks=jnp.stack(clocks_l, axis=0))
+        cost_j = jnp.sum(jnp.where(locpub, cost_m, 0.0), axis=1) + xeg_j
         if not faulty:
-            cost_j = jnp.sum(jnp.where(locpub, cost_m, 0.0), axis=1) + xeg_j
-            return _emitted(dict(
-                cost_j=cost_j, init_off=off,
-                qexit=qexit, clocks=clocks, trips=trips,
-                public_mask=locpub, start=start, end=end,
-                completion=completion,
-                provider=jnp.where(locpub, prov_m, -1),
-                replica=rep_m,
-                segment=jnp.where(locpub, seg_m, -1),
-                attempts=locpub.astype(jnp.int64),
-                failed=jnp.zeros((J, M), dtype=jnp.int64),
-                abandoned=jnp.zeros(J, dtype=bool),
-                queue_wait=qwait, cold=coldm), **flags)
-        # abandoned jobs never complete: NaN completion, NaN stage ends
-        ok_j = ~ab_j
-        completion_out = jnp.where(ok_j, completion, jnp.nan)
-        cost_j = (jnp.sum(jnp.where(locpub, cost_m, 0.0), axis=1)
-                  + xeg_j + lost_j)
-        return _emitted(dict(
-            cost_j=cost_j, init_off=off,
-            qexit=qexit, clocks=clocks, trips=trips,
-            public_mask=locpub, start=start,
-            end=jnp.where(jnp.isinf(end), jnp.nan, end),
-            completion=completion_out,
-            provider=jnp.where(locpub, prov_m, -1),
-            replica=rep_m,
-            segment=jnp.where(locpub, seg_m, -1),
-            attempts=jnp.stack(att_l, axis=1),
-            failed=jnp.stack(failc_l, axis=1),
-            abandoned=ab_j,
-            queue_wait=qwait, cold=coldm), **flags)
+            out.update(cost_j=cost_j, end=end, completion=completion,
+                       attempts=locpub.astype(jnp.int64),
+                       failed=jnp.zeros((J, M), dtype=jnp.int64),
+                       abandoned=jnp.zeros(J, dtype=bool))
+        else:
+            # abandoned jobs never complete: NaN completion, NaN stage ends
+            out.update(cost_j=cost_j + lost_j,
+                       end=jnp.where(jnp.isinf(end), jnp.nan, end),
+                       completion=jnp.where(~ab_j, completion, jnp.nan),
+                       attempts=jnp.stack(att_l, axis=1),
+                       failed=jnp.stack(failc_l, axis=1), abandoned=ab_j)
+        return _emitted(out, faulty=faulty, capped=capped, cold=cold)
 
     return run_one
 
-
-#: outputs that only the pager reads; a monolithic call leaves them on
-#: the device
-_CARRY_OUTPUTS = ("qexit", "clocks")
 
 #: the index outputs, which cross as int32 (they lie in [-1, bound) for
 #: bounds of a few providers, replica slots or price segments; int8
@@ -1318,12 +1348,12 @@ def _engine_fn(M: int, I_max: int, J: int, P: int, S: int,
                include_transfers: bool, init_mode: int, adaptive: bool,
                A_att: int, W: int, faulty: bool, lookahead: bool,
                capped: bool, cold: bool, pooled: bool, C: int,
-               n_dev: int, impl: str = "scan"):
+               n_dev: int, impl: str = "scan", *, carry: bool = False):
     """jit(vmap) on one device; pmap(vmap) sharding the scenario axis
     across host devices when more are available."""
     run_one = _build_engine(M, I_max, J, P, S, include_transfers, init_mode,
                             adaptive, A_att, W, faulty, lookahead,
-                            capped, cold, pooled, C, impl)
+                            capped, cold, pooled, C, impl, carry)
     if n_dev > 1:
         return jax.pmap(jax.vmap(run_one))
     return jax.jit(jax.vmap(run_one))
@@ -1724,6 +1754,8 @@ class _Task:
         pinned[:M] = dag.must_private_mask[topo]
         inert = np.ones(M_pad, dtype=bool)
         inert[:M] = False
+        # each stage's predecessors, in relabelled indices (the pager's cut)
+        self.preds = [np.flatnonzero(A[:, k]) for k in range(M_pad)]
 
         # per-(config, grid) replica pools as [M_pad, I_max] speed
         # matrices: finite entry = present replica with that slowdown,
@@ -1896,7 +1928,7 @@ class _Task:
     _PAGE_J_AXES = {0: 1, 1: 1, 2: 1, 3: 1, 4: 1, 5: 1, 6: 3, 7: 3,
                     11: 1, 12: 1, 14: 1, 15: 1, 16: 1}
     _N_BASE_ARGS = 24
-    _IDX_DEADLINE, _IDX_RELEASE = 12, 14
+    _IDX_DEADLINE, _IDX_T0, _IDX_RELEASE = 12, 13, 14
     _IDX_INIT_ELIG, _IDX_LIVE, _IDX_CLOCK0 = 15, 16, 23
 
     def eff_modes(self, init_phase: bool, adaptive: bool) -> Tuple[int, bool]:
@@ -1999,13 +2031,11 @@ class _Task:
             cold=out["cold"][:, :, inv])
 
 
-def _dispatch(fn, args, S: int, n_dev: int, *,
-              carry: bool = False) -> Dict[str, np.ndarray]:
+def _dispatch(fn, args, S: int, n_dev: int) -> Dict[str, np.ndarray]:
     """Run a compiled engine over scenario-axis args, sharding across
     host devices, and return its outputs as numpy arrays, as the engine
     emits them (:func:`_emitted`; :func:`_finalize` restores the rest).
-    The pager's outputs (``qexit``, ``clocks``) are copied back only
-    with ``carry``; every copy starts before the first is waited on.
+    Every copy starts before the first is waited on.
 
     The host side is timed in four spans (``h2d``, ``launch``, ``wait``,
     ``d2h``); the call's bytes each way, the arrays copied back and its
@@ -2031,8 +2061,6 @@ def _dispatch(fn, args, S: int, n_dev: int, *,
         with _span("wait"):
             jax.block_until_ready(out)
         with _span("d2h"):
-            out = {k: v for k, v in out.items()
-                   if carry or k not in _CARRY_OUTPUTS}
             for v in out.values():
                 v.copy_to_host_async()
             raw = {k: np.asarray(v) for k, v in out.items()}
@@ -2102,17 +2130,36 @@ def _host_init_offload(task: _Task) -> np.ndarray:
                                        init_elig)])
 
 
-# most recent paged run's page/retry counts (observability hook for the
-# streaming tests and the throughput bench; not part of the result API)
-_LAST_PAGE_STATS: Dict[str, int] = {}
-
 # most recent sweep's host spans in seconds (``prep_s``, ``plan_s``,
 # ``engine_s`` and, inside it, ``h2d_s``/``launch_s``/``wait_s``/``d2h_s``,
-# ``finalize_s``), its counters (``engine_calls``, ``h2d_bytes``,
-# ``d2h_arrays``, ``d2h_bytes``, ``loop_trips``, ``lane_trips``,
-# ``lane_slots``) and the engine impl that ran it; not part of the result API
-# (docs/architecture.md, "Reading a sweep's spans and counters")
+# ``carry_s``, ``finalize_s``), its counters (``engine_calls``,
+# ``h2d_bytes``, ``d2h_arrays``, ``d2h_bytes``, ``loop_trips``,
+# ``lane_trips``, ``lane_slots``; ``pages``, ``carried_jobs``,
+# ``page_retries`` when paged) and the engine impl that ran it; not part of
+# the result API (docs/architecture.md, "Reading a sweep's spans and
+# counters")
 _LAST_RUN_STATS: Dict[str, object] = {}
+
+
+class _PageStats(Mapping):
+    """The most recent sweep's paging counters under the names the
+    benchmark's record reads (``bench/system.py``), a view of
+    :data:`_LAST_RUN_STATS`: ``pages``, and ``retries`` for
+    ``page_retries``. Empty after a sweep that did not page."""
+
+    _NAMES = {"pages": "pages", "retries": "page_retries"}
+
+    def __getitem__(self, key):
+        return _LAST_RUN_STATS[self._NAMES[key]]
+
+    def __iter__(self):
+        return (k for k, v in self._NAMES.items() if v in _LAST_RUN_STATS)
+
+    def __len__(self):
+        return sum(1 for _ in self)
+
+
+_LAST_PAGE_STATS = _PageStats()
 
 # id of each sweep, the one argument of its root ``vs:sweep`` annotation
 _SWEEP_IDS = itertools.count(1)
@@ -2144,85 +2191,112 @@ def _count(name: str, n) -> None:
     _LAST_RUN_STATS[name] = _LAST_RUN_STATS.get(name, 0) + n
 
 
+def _decided(task: _Task, qexit: np.ndarray, t_cut: float) -> np.ndarray:
+    """[S, n, M] bool: the (job, stage) decisions a cut at ``t_cut`` leaves
+    final, from a page's sign-encoded queue exits ``qexit`` [S, n, M]: a
+    stage that left its queue before the cut (or never queued), once
+    every predecessor is final (stages are in topological order)."""
+    with np.errstate(invalid="ignore"):
+        done = ~(np.where(qexit < -0.5, -qexit - 1.0, qexit) >= t_cut)
+    for k in range(task.M_pad):
+        for u in task.preds[k]:
+            done[:, :, k] &= done[:, :, u]
+    return done
+
+
 def _run_paged(task: _Task, I_max: int, include_transfers: bool,
                init_phase: bool, init_mode: int, adaptive: bool,
                lookahead: bool, chunk: int, n_dev: int,
                impl: str = "scan") -> Dict[str, np.ndarray]:
     """Page the job axis through fixed-J compiled executables.
 
-    Jobs are paged in release order (whole tied-release groups per page,
-    page members in ascending canonical job order); each page starts from
-    the previous pages' final per-replica clocks. The decomposition is
-    *checked*, not assumed: if any committed job's queue exit (dispatch
-    or eviction instant, at any stage) lands at or after the next page's
-    first release, the two pages could have co-resided in a stage queue
-    — the page retries at double size (a saturated retry is the
-    monolithic computation, so the fallback is always exact). Pages pad
-    to the ``chunk * 2**k`` family sizes, so the compile cache is keyed
-    on the chunk size, not the total job count. Init offload — a global
-    capacity-prefix rule — resolves host-side over the full job set
-    before any paging.
+    Jobs enter pages in release order, whole tied-release groups at a
+    time. A page's engine call resumes every stage's event loop at the
+    page's cut (its first release) and runs its jobs to completion; the
+    next page's first release, ``T_next``, is the next cut. Events before
+    a cut are the monolithic run's: every later release, and so every
+    later job's arrival at any stage, comes at or after it. So at
+    ``T_next`` the page commits each job whose queue exits, at every
+    stage, all lie before ``T_next``. Every other job is *carried* into
+    the next page:
+
+    * a stage that left its queue before the cut (or never queued) with
+      every predecessor decided keeps its decision: the next call gets
+      its queue exit and replica (``fixed``) and recomputes the rest;
+    * its other stages rerun there, those it reached before the cut as
+      members of their stage queues at the cut, with their ready times;
+    * each replica's busy-until clock at the cut goes along, as the
+      engine's loop left it after its last step before ``T_next``.
+
+    The next call then sees exactly the queues and clocks the monolithic
+    run has at ``T_next``, so its decisions are the monolithic run's, bit
+    for bit, and no page is ever run twice. A job carried in any scenario
+    is carried in all (the job axis is shared); where it was committed,
+    every stage is fixed. Carried jobs count against the page: its
+    family is the smallest ``chunk * 2**k`` that they fill at most half
+    of, the rest new jobs. Init offload — a global capacity-prefix rule
+    — resolves host-side over the full job set before any paging.
     """
-    S, J = task.S, task.J
-    rel = task.release
-    order = np.argsort(rel, kind="stable")
-    rel_sorted = rel[order]
+    S, J, M = task.S, task.J, task.M_pad
+    order = np.argsort(task.release, kind="stable")
+    rel_sorted = task.release[order]
     with _span("plan"):
         off_full = task.init_plan(init_phase)
     bufs: Optional[Dict[str, np.ndarray]] = None
     clocks = task.args[task._IDX_CLOCK0]
-    pos, size = 0, int(chunk)
-    n_pages = n_retries = 0
+    t_cut = task.t0
+    carried = np.zeros(0, dtype=np.int64)
+    fixed_c = np.zeros((S, 0, M, 2))  # the carried jobs' decided stages
+    pos = 0
     while pos < J:
-        end = min(pos + size, J)
+        J_fam = int(chunk)
+        while 2 * len(carried) > J_fam:
+            J_fam *= 2
+        end = min(pos + J_fam - len(carried), J)
         # never split a tied-release group across pages: an epoch's jobs
         # admit together before the sweep in both engines
         while end < J and rel_sorted[end] == rel_sorted[end - 1]:
             end += 1
-        idx = np.sort(order[pos:end])
+        idx = np.concatenate([carried, order[pos:end]])
         n = len(idx)
-        J_fam = int(chunk)
         while J_fam < n:
             J_fam *= 2
         T_next = rel_sorted[end] if end < J else np.inf
-        args = task.page_args(idx, J_fam, off_full[:, idx], clocks)
+        args = list(task.page_args(idx, J_fam, off_full[:, idx], clocks))
+        args[task._IDX_T0] = np.broadcast_to([t_cut, T_next], (S, 2))
+        fixed = np.full((S, J_fam, M, 2), np.nan)
+        fixed[:, :len(carried)] = fixed_c
         fn = _engine_fn(task.M_pad, I_max, J_fam, task.n_providers,
                         task.n_segments, include_transfers,
                         init_mode, adaptive,
                         task.n_attempts, task.n_windows, task.faulty,
                         lookahead, task.capped, task.cold, task.pooled,
-                        task.C, n_dev, impl)
-        out = _dispatch(fn, args, S, n_dev, carry=True)
-        qx = out.pop("qexit")[:, :n, :]
-        with np.errstate(invalid="ignore"):
-            exit_t = np.where(qx < -0.5, -qx - 1.0, qx)
-            unsafe = bool(np.any(exit_t >= T_next))  # NaN compares False
-        if unsafe and end < J:
-            # grow the page to the stream's next quiet point: every job
-            # released before the latest in-page queue exit must share
-            # the page. Strictly increasing (the violating exit is at or
-            # past the next release), and it jumps straight to natural
-            # burst boundaries — a dense stream whose exits overlap all
-            # later releases saturates to the monolithic run in one
-            # retry.
-            t_quiet = float(np.nanmax(exit_t))
-            size = int(np.searchsorted(rel_sorted, t_quiet,
-                                       side="right")) - pos
-            n_retries += 1
-            continue
+                        task.C, n_dev, impl, carry=True)
+        out = _dispatch(fn, tuple(args) + (fixed,), S, n_dev)
+        with _span("carry"):
+            qx = out.pop("qexit")[:, :n]                    # [S, n, M]
+            done = _decided(task, qx, T_next)
+            keep = ~done.all(axis=(0, 2))
+            carried = idx[keep]
+            fixed_c = np.where(
+                (done & ~np.isnan(qx))[:, keep, :, None],
+                np.stack([qx, out["replica"][:, :n]], axis=-1)[:, keep],
+                np.nan)
         clocks = out.pop("clocks")
         out.pop("trips")  # [S, M]: per page, already counted
         if bufs is None:
             bufs = {name: np.empty((S, J) + v.shape[2:], dtype=v.dtype)
                     for name, v in out.items()}
+        # a carried job's row is written again by the page that commits it
         for name, v in out.items():
             bufs[name][:, idx] = v[:, :n]
-        pos, size = end, int(chunk)
-        n_pages += 1
-    assert bufs is not None
-    # observability (tests / bench reporting): pages committed + safety
-    # retries of the most recent paged run
-    _LAST_PAGE_STATS.update(pages=n_pages, retries=n_retries)
+        pos, t_cut = end, T_next
+        _count("pages", 1)
+        if pos < J:
+            _count("carried_jobs", len(carried))
+    assert bufs is not None and not len(carried)
+    # every flag family the pager accepts carries its state: none retries
+    _count("page_retries", 0)
     return bufs
 
 
@@ -2331,10 +2405,10 @@ def simulate_scenarios(
     seconds of ``t0`` (``None`` = all jobs, the pre-window behavior).
 
     ``chunk_jobs`` turns the job axis into a *paged* dimension: the
-    vector engine runs arrival windows of at most that many jobs per
-    fixed-J compiled executable (carrying per-replica clocks between
-    pages, with a queue-overlap safety check that falls back to larger
-    pages), and the DES admits arrival epochs into its heap one window
+    vector engine runs arrival windows of about that many jobs per
+    fixed-J compiled executable (carrying the jobs still queued at each
+    cut and the per-replica clocks into the next page, each page run
+    once), and the DES admits arrival epochs into its heap one window
     at a time — results are identical to the monolithic path on
     tie-free streams. ``egress_lookahead`` adds a one-edge downstream
     egress term to the placement argmin (predicted successor-edge

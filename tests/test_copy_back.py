@@ -1,7 +1,8 @@
 """The engine's copy-back leaves on the device what the host can derive.
 
-A monolithic call copies back neither the pager's carry (``qexit``,
-``clocks``) nor the outputs its static flags make constant (the fault
+A monolithic call emits none of the pager's outputs (``qexit``,
+``clocks``: only a page's engine does) and copies back none of the
+outputs its static flags make constant (the fault
 counters unless faulty, ``queue_wait`` unless capped, ``cold`` unless
 cold), and copies the index outputs as int32; the host rebuilds and
 widens them (``vectorsim._emitted`` / ``_restored``). Each static-flag
@@ -74,7 +75,7 @@ FAMILIES = {
     "capped": (lambda: _loaded(concurrency=1), 11,
                lambda r: (r.queue_wait > 0).any()),
     "cold": (lambda: _loaded(coldstart=CS), 11, lambda r: r.cold.any()),
-    "paged": (_paged, 12, lambda r: vectorsim._LAST_PAGE_STATS["pages"] > 1),
+    "paged": (_paged, 12, lambda r: vectorsim._LAST_RUN_STATS["pages"] > 1),
 }
 
 

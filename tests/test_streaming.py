@@ -1,17 +1,24 @@
 """Streaming/paged job axis: chunked == monolithic, DES == vector.
 
 The paged path must be *indistinguishable* from the monolithic one: the
-vector engine pages jobs through fixed-shape chunks (per-replica clocks
-carried across pages, safety-checked decomposition with doubling
-fallback) and the DES admits arrival epochs in windows — neither may
-change a single field of the result. The suite pins:
+vector engine pages jobs through fixed-shape chunks (each page resumes
+from the queues and per-replica clocks at its cut, jobs still queued
+there carried into the next page) and the DES admits arrival epochs in
+windows — neither may change a single field of the result. The suite
+pins:
 
 * chunked vs monolithic bit-exactness on the vector engine
-  (``chunk_jobs`` in {J, J/2, 17}), every SimResult field including
+  (``chunk_jobs`` in {J, J/2, 17, 7}), every SimResult field including
   provider/segment/replica/attempts, with multi-page execution actually
-  exercised (page-stats hook);
+  exercised (the ``pages`` counter);
 * DES windowed admission bit-exact vs the monolithic DES, and
   DES == vector at every chunk size tested;
+* carry: on streams whose queues span the cuts (the chain image DAG,
+  the video DAG's fan-out and join, a stream denser than the service
+  rate) every page is one engine call and jobs are carried, a fan-out
+  job with one branch decided included, and on every flag family the
+  pager accepts (faults, lookahead, adaptive off, init offload modes,
+  replica / straggler / price axes, transfers off, sharded devices);
 * the paged path under the full scenario stack (portfolio, price
   traces, faults, init offload);
 * the ``azure:`` workload family: spec parsing, determinism,
@@ -22,6 +29,8 @@ change a single field of the result. The suite pins:
 * a hypothesis property: total cost and makespan are invariant to the
   chunk size.
 """
+import os
+
 import numpy as np
 import pytest
 
@@ -46,6 +55,14 @@ def burst_workload(dag, J, seed, burst=8, gap=1000.0):
     return pred, act, release
 
 
+def poisson_stream(dag, J, seed, gap=0.8):
+    """Exponential gaps of mean ``gap`` seconds against stage times of
+    about 2 s on two replicas: queues form and span the cuts."""
+    pred, act = workload(dag, J, seed)
+    rng = np.random.default_rng(seed + 91)
+    return pred, act, np.cumsum(rng.exponential(gap, J))
+
+
 def assert_bit_exact(a, b):
     for fld in FIELDS + ("public_mask",):
         x = np.nan_to_num(np.asarray(getattr(a, fld), float), nan=-1.0)
@@ -61,20 +78,19 @@ def run_vec(dag, pred, act, release, chunk, **kw):
 
 # -- chunked vs monolithic, vector engine -------------------------------
 
-@pytest.mark.parametrize("chunk", [J, J // 2, 17])
+@pytest.mark.parametrize("chunk", [J, J // 2, 17, 7])
 def test_chunked_bit_exact_vs_monolithic(chunk):
     dag = APPS["image"]
     pred, act, release = burst_workload(dag, J, seed=3)
     kw = dict(c_max_grid=(8.0, 40.0), orders=("spt", "hcf"))
     mono = run_vec(dag, pred, act, release, None, **kw)
-    vectorsim._LAST_PAGE_STATS.clear()
     paged = run_vec(dag, pred, act, release, chunk, **kw)
     assert_bit_exact(paged, mono)
     if chunk < J:
-        assert vectorsim._LAST_PAGE_STATS["pages"] > 1
+        assert vectorsim._LAST_RUN_STATS["pages"] > 1
 
 
-@pytest.mark.parametrize("chunk", [J, J // 2, 17])
+@pytest.mark.parametrize("chunk", [J, J // 2, 17, 7])
 def test_chunked_matches_des(chunk):
     dag = APPS["image"]
     pred, act, release = burst_workload(dag, J, seed=5)
@@ -88,17 +104,144 @@ def test_chunked_matches_des(chunk):
     assert_bit_exact(d, d_mono)
 
 
-def test_unsafe_pages_fall_back_by_doubling():
-    """A dense stream (every page's work overlaps the next release) must
-    still be exact: the safety check retries at doubled page size."""
+def test_dense_stream_pages_by_carry():
+    """A dense stream (every page's work overlaps the next release) is
+    exact with no page run twice: the queued jobs are carried."""
     dag = APPS["image"]
     pred, act = workload(dag, 32, seed=9)
     release = np.linspace(0.0, 1.0, 32)  # far denser than the service rate
     mono = run_vec(dag, pred, act, release, None, c_max_grid=(15.0,))
-    vectorsim._LAST_PAGE_STATS.clear()
     paged = run_vec(dag, pred, act, release, 4, c_max_grid=(15.0,))
     assert_bit_exact(paged, mono)
-    assert vectorsim._LAST_PAGE_STATS["retries"] > 0
+    st = vectorsim._LAST_RUN_STATS
+    assert st["pages"] > 1 and st["carried_jobs"] > 0
+    assert st["engine_calls"] == st["pages"] and st["page_retries"] == 0
+    d = simulate_scenarios(dag, pred, act, arrivals=release, chunk_jobs=4,
+                           c_max_grid=(15.0,), engine="des")
+    assert_equivalent(paged.scenario(0), d.scenario(0))
+
+
+def _carry_check(dag, pred, act, release, chunk, **kw):
+    """Paged == monolithic bit for bit, one engine call a page, jobs
+    carried, and == the DES field by field in every scenario."""
+    mono = run_vec(dag, pred, act, release, None, **kw)
+    paged = run_vec(dag, pred, act, release, chunk, **kw)
+    assert_bit_exact(paged, mono)
+    st = dict(vectorsim._LAST_RUN_STATS)
+    des = simulate_scenarios(dag, pred, act, arrivals=release,
+                             chunk_jobs=chunk, engine="des", **kw)
+    for s in range(paged.num_scenarios):
+        assert_equivalent(paged.scenario(s), des.scenario(s))
+    return st
+
+
+@pytest.mark.parametrize("chunk", [48, 24, 17, 7])
+@pytest.mark.parametrize("app", ["image", "video"])
+def test_carried_queues_exact(app, chunk):
+    """Queues span the cuts on the chain and on the fan-out/join DAG."""
+    dag = APPS[app]
+    pred, act, release = poisson_stream(dag, 48, seed=31)
+    st = _carry_check(dag, pred, act, release, chunk,
+                      c_max_grid=(10.0, 30.0), orders=("spt", "hcf"))
+    if chunk < 48:
+        assert st["carried_jobs"] > 0
+        assert st["engine_calls"] == st["pages"] > 1
+        assert st["page_retries"] == 0
+
+
+def test_fanout_job_carried_with_one_branch_decided(monkeypatch):
+    """A video job whose DO branch left its queue before the cut, while
+    its RI branch was still queued, enters the next page with DO fixed
+    and RI back in its queue."""
+    dag = APPS["video"]
+    pred, act, release = poisson_stream(dag, 48, seed=31)
+    calls = []
+    orig = vectorsim._dispatch
+
+    def spy(fn, args, S, n_dev):
+        out = orig(fn, args, S, n_dev)
+        calls.append((args, dict(out)))
+        return out
+
+    monkeypatch.setattr(vectorsim, "_dispatch", spy)
+    _carry_check(dag, pred, act, release, 7, c_max_grid=(10.0, 30.0),
+                 orders=("spt", "hcf"))
+    b1, b2 = 1, 2  # DO and RI in topological order: EF, DO, RI, ME
+    found = False
+    for args, out in calls:
+        fixed, qx = args[-1], out.get("qexit")
+        if qx is None:
+            continue  # the monolithic call
+        t_cut = args[vectorsim._Task._IDX_T0][:, :1]  # the page's own cut
+        for a, b in ((b1, b2), (b2, b1)):
+            requeued = (~np.isnan(fixed[:, :, a, 0])
+                        & np.isnan(fixed[:, :, b, 0])
+                        & (np.abs(qx[:, :, b]) >= t_cut))
+            found |= bool(requeued.any())
+    assert found
+
+
+def _families():
+    from repro.core.cost import PriceTrace, demo_portfolio
+    pf = demo_portfolio(3)
+    spot = [PriceTrace((1.7e-8, 0.8e-8, 2.4e-8), breakpoints=(20.0, 45.0))
+            ] * 3
+    return {
+        "faulty": dict(portfolio=pf, faults=[None, 0.3], retry=None),
+        "lookahead": dict(portfolio=pf, egress_lookahead=True),
+        "adaptive-off": dict(adaptive=False),
+        "init-off": dict(init_phase=False),
+        "init-window": dict(init_window=8.0),
+        "offload-mask": dict(offload_mask=np.arange(40) % 5 == 0),
+        "no-transfers": dict(include_transfers=False),
+        "replica-axes": dict(replicas=[[1, 2, 3], [2, 2, 2]],
+                             replica_speeds=[None, {(1, 0): 2.5}]),
+        "price-traces": dict(portfolio=pf, price_traces=[None, spot]),
+    }
+
+
+@pytest.mark.parametrize("family", sorted(_families()))
+def test_every_flag_family_pages_by_carry(family):
+    dag = APPS["image"]
+    pred, act, release = poisson_stream(dag, 40, seed=41)
+    kw = dict(c_max_grid=(12.0,), orders=("spt",), **_families()[family])
+    st = _carry_check(dag, pred, act, release, 9, **kw)
+    assert st["carried_jobs"] > 0 and st["page_retries"] == 0
+    assert st["engine_calls"] == st["pages"] > 1
+
+
+def test_sharded_pages_match_one_device(tmp_path):
+    """The scenario axis sharded over four devices pages by carry to the
+    one-device result."""
+    from tests._subproc import run_py
+
+    path = tmp_path / "sharded.npz"
+    code = f"""
+import jax, numpy as np
+from repro.core import APPS, vectorsim
+from tests.test_streaming import poisson_stream, run_vec
+assert jax.local_device_count() == 4
+dag = APPS["video"]
+pred, act, release = poisson_stream(dag, 48, seed=31)
+res = run_vec(dag, pred, act, release, 7, c_max_grid=(10.0, 30.0),
+              orders=("spt", "hcf"))
+st = vectorsim._LAST_RUN_STATS
+np.savez({str(path)!r}, carried=st["carried_jobs"], start=res.start,
+         end=res.end, replica=res.replica, public_mask=res.public_mask,
+         cost_usd=res.cost_usd)
+"""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    run_py(f"import sys; sys.path.insert(0, {root!r})\n" + code, devices=4)
+    sharded = np.load(path)
+    dag = APPS["video"]
+    pred, act, release = poisson_stream(dag, 48, seed=31)
+    res = run_vec(dag, pred, act, release, 7, c_max_grid=(10.0, 30.0),
+                  orders=("spt", "hcf"))
+    assert int(sharded["carried"]) == vectorsim._LAST_RUN_STATS[
+        "carried_jobs"] > 0
+    for f in ("start", "end", "replica", "public_mask", "cost_usd"):
+        np.testing.assert_array_equal(sharded[f], getattr(res, f),
+                                      err_msg=f)
 
 
 def test_chunked_full_scenario_stack():
