@@ -1349,14 +1349,46 @@ def _engine_fn(M: int, I_max: int, J: int, P: int, S: int,
                A_att: int, W: int, faulty: bool, lookahead: bool,
                capped: bool, cold: bool, pooled: bool, C: int,
                n_dev: int, impl: str = "scan", *, carry: bool = False):
-    """jit(vmap) on one device; pmap(vmap) sharding the scenario axis
-    across host devices when more are available."""
-    run_one = _build_engine(M, I_max, J, P, S, include_transfers, init_mode,
-                            adaptive, A_att, W, faulty, lookahead,
-                            capped, cold, pooled, C, impl, carry)
+    """The engine's program over a call's packed arguments
+    (:func:`_pack`): ``fn(layout, *buffers)``. It cuts the buffers back
+    into the arguments (:func:`_unpacked`) and runs vmap(run_one) over
+    the scenario axis: jit on one device; pmap sharding the scenario axis
+    across host devices when more are available. ``layout`` is static,
+    one per shape family."""
+    engine = _build_engine(M, I_max, J, P, S, include_transfers, init_mode,
+                           adaptive, A_att, W, faulty, lookahead,
+                           capped, cold, pooled, C, impl, carry)
+
+    def run_one(layout, *bufs):
+        return jax.vmap(engine)(*_unpacked(layout, bufs))
+
     if n_dev > 1:
-        return jax.pmap(jax.vmap(run_one))
-    return jax.jit(jax.vmap(run_one))
+        return jax.pmap(run_one, static_broadcasted_argnums=0)
+    return jax.jit(run_one, static_argnums=0)
+
+
+def _pack(args: Sequence[np.ndarray]) -> Tuple[tuple, List[np.ndarray]]:
+    """One engine call's arguments, each ``[S, ...]``, as one contiguous
+    ``[S, L]`` host buffer per dtype (in order of first use), scenario
+    axis leading, and the static layout that cuts them back: per
+    argument, (buffer, offset, shape less the scenario axis)."""
+    S = args[0].shape[0]
+    cols: Dict[np.dtype, List[np.ndarray]] = {}
+    layout = []
+    for a in args:
+        rows = cols.setdefault(a.dtype, [])
+        layout.append((list(cols).index(a.dtype),
+                       sum(r.shape[1] for r in rows), a.shape[1:]))
+        rows.append(a.reshape(S, -1))
+    return tuple(layout), [np.concatenate(c, axis=1) for c in cols.values()]
+
+
+def _unpacked(layout: tuple, bufs: Sequence[jax.Array]) -> List[jax.Array]:
+    """The arguments :func:`_pack` packed, cut out of the ``[S, L]``
+    buffers with static slices and reshapes: bit for bit."""
+    return [bufs[b][:, off:off + int(np.prod(shape))].reshape(
+                bufs[b].shape[:1] + shape)
+            for b, off, shape in layout]
 
 
 def _norm_batch(d: Dict[str, np.ndarray], B: int) -> Dict[str, np.ndarray]:
@@ -2037,27 +2069,25 @@ def _dispatch(fn, args, S: int, n_dev: int) -> Dict[str, np.ndarray]:
     emits them (:func:`_emitted`; :func:`_finalize` restores the rest).
     Every copy starts before the first is waited on.
 
-    The host side is timed in four spans (``h2d``, ``launch``, ``wait``,
-    ``d2h``); the call's bytes each way, the arrays copied back and its
-    lanes' while-loop trips are counted (see :data:`_LAST_RUN_STATS`)."""
+    The arguments cross in one transfer per dtype (:func:`_pack`): a
+    transfer costs about as much per array as per megabyte. The host
+    side is timed in four spans (``h2d``, ``launch``, ``wait``,
+    ``d2h``); the call's bytes each way, the arrays handed over and
+    copied back and its lanes' while-loop trips are counted (see
+    :data:`_LAST_RUN_STATS`)."""
     with jax.enable_x64(True):
         with _span("h2d"):
+            layout, bufs = _pack(args)
             if n_dev > 1:
                 # strided scenario->device interleave balances
                 # heterogeneous grids across the lockstep shards
                 pad = (-S) % n_dev
                 sel = np.arange(S + pad) % S
                 perm = sel.reshape(-1, n_dev).T.reshape(-1)
-
-                def shard(x):
-                    x = np.ascontiguousarray(x[perm])
-                    return jnp.asarray(x.reshape((n_dev, -1) + x.shape[1:]))
-
-                dev_args = [shard(a) for a in args]
-            else:
-                dev_args = [jnp.asarray(a) for a in args]
+                bufs = [x[perm].reshape(n_dev, -1, x.shape[1]) for x in bufs]
+            dev_args = [jnp.asarray(x) for x in bufs]
         with _span("launch"):
-            out = fn(*dev_args)
+            out = fn(layout, *dev_args)
         with _span("wait"):
             jax.block_until_ready(out)
         with _span("d2h"):
@@ -2075,6 +2105,7 @@ def _dispatch(fn, args, S: int, n_dev: int) -> Dict[str, np.ndarray]:
             else:
                 out = raw
     _count("engine_calls", 1)
+    _count("h2d_arrays", len(dev_args))
     _count("h2d_bytes", sum(int(x.nbytes) for x in dev_args))
     _count("d2h_arrays", len(raw))
     _count("d2h_bytes", sum(int(x.nbytes) for x in raw.values()))
@@ -2133,8 +2164,8 @@ def _host_init_offload(task: _Task) -> np.ndarray:
 # most recent sweep's host spans in seconds (``prep_s``, ``plan_s``,
 # ``engine_s`` and, inside it, ``h2d_s``/``launch_s``/``wait_s``/``d2h_s``,
 # ``carry_s``, ``finalize_s``), its counters (``engine_calls``,
-# ``h2d_bytes``, ``d2h_arrays``, ``d2h_bytes``, ``loop_trips``,
-# ``lane_trips``, ``lane_slots``; ``pages``, ``carried_jobs``,
+# ``h2d_arrays``, ``h2d_bytes``, ``d2h_arrays``, ``d2h_bytes``,
+# ``loop_trips``, ``lane_trips``, ``lane_slots``; ``pages``, ``carried_jobs``,
 # ``page_retries`` when paged) and the engine impl that ran it; not part of
 # the result API (docs/architecture.md, "Reading a sweep's spans and
 # counters")

@@ -120,8 +120,9 @@ def test_pruned_copy_back_gives_the_full_outputs(family, monkeypatch):
 
 def test_sharded_call_gives_the_one_device_outputs(tmp_path):
     """The scenario axis sharded over four devices (the pmap branch of
-    `_dispatch`: copy-back, reshape and gather) gives the one-device
-    result, field for field, with as many arrays copied per call."""
+    `_dispatch`: packed hand-off, copy-back, reshape and gather) gives
+    the one-device result, field for field, with as many arrays handed
+    over and copied per call."""
     from tests._subproc import run_py
 
     path = tmp_path / "sharded.npz"
@@ -131,16 +132,20 @@ from repro.core import vectorsim
 from tests.test_copy_back import _plain, _sweep
 assert jax.local_device_count() == 4
 res, copied = _sweep(*_plain())
+st = vectorsim._LAST_RUN_STATS
 arrays = {{f.name: np.asarray(getattr(res, f.name))
           for f in dataclasses.fields(res)
           if isinstance(getattr(res, f.name), np.ndarray)}}
-np.savez({str(path)!r}, copied=copied, **arrays)
+np.savez({str(path)!r}, copied=copied,
+         handed=st["h2d_arrays"] / st["engine_calls"], **arrays)
 """
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     run_py(f"import sys; sys.path.insert(0, {root!r})\n" + code, devices=4)
     sharded = np.load(path)
     res, copied = _sweep(*_plain())
     assert float(sharded["copied"]) == copied == 10
+    st = vectorsim._LAST_RUN_STATS
+    assert float(sharded["handed"]) == st["h2d_arrays"] == 2
     for f in dataclasses.fields(res):
         if f.name not in sharded:
             continue

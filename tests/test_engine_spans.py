@@ -3,13 +3,14 @@
 Every sweep times its host phases as ``vs:`` spans (on the profiler's
 host plane and in the record), splits each engine call into transfer,
 launch, wait and copy-back, counts the call's bytes each way and the
-arrays copied back, and counts the while-loop trips its lanes needed
+arrays handed over and copied back, and counts the while-loop trips its lanes needed
 against those the lockstep loop ran. The suite pins:
 
 * every key in the record after a sweep on each inner-loop impl, the
   byte counts against the shapes of what crossed (the engine's outputs
-  at their emitted widths, less the pager's carry), the ten arrays a
-  monolithic call copies back, and the four ``_dispatch`` spans inside
+  at their emitted widths, less the pager's carry), the two buffers a
+  monolithic call hands over and the ten arrays it copies back, and the
+  four ``_dispatch`` spans inside
   ``engine_s``;
 * occupancy: 100% for a one-scenario call, and a hand reduction of the
   ``trips`` output for a fused call of unequal scenarios;
@@ -31,8 +32,8 @@ from tests.test_vectorsim import grid_for, workload
 
 SPANS = ("prep_s", "plan_s", "engine_s", "h2d_s", "launch_s", "wait_s",
          "d2h_s", "finalize_s")
-COUNTERS = ("engine_calls", "h2d_bytes", "d2h_arrays", "d2h_bytes",
-            "loop_trips", "lane_trips", "lane_slots")
+COUNTERS = ("engine_calls", "h2d_arrays", "h2d_bytes", "d2h_arrays",
+            "d2h_bytes", "loop_trips", "lane_trips", "lane_slots")
 #: what a monolithic call of a fault-free, uncapped, warm engine copies
 #: back: the index outputs as int32, the pager's carry and the outputs
 #: its flags make constant left on the device
@@ -50,8 +51,9 @@ def _nbytes(arrays):
 
 def _emitted_shapes(fn, args):
     """Shapes and dtypes of everything the compiled engine emits."""
+    layout, bufs = vectorsim._pack(args)
     with jax.enable_x64(True):
-        return jax.eval_shape(fn, *args)
+        return jax.eval_shape(lambda *b: fn(layout, *b), *bufs)
 
 
 @pytest.fixture
@@ -101,6 +103,8 @@ def test_record_holds_every_span_and_counter(impl, calls):
     assert st["plan_s"] <= st["prep_s"]
     (args, out, emitted), = calls
     assert st["engine_calls"] == 1
+    # the 24 arguments cross as one float64 and one bool buffer
+    assert len(args) == 24 and st["h2d_arrays"] == 2
     assert st["h2d_bytes"] == _nbytes(args)
     copied = {k: v for k, v in emitted.items()
               if k not in ("qexit", "clocks")}
@@ -169,6 +173,7 @@ def test_paged_stream_is_exact_and_counted_per_page(calls, monkeypatch):
     st = vectorsim._LAST_RUN_STATS
     assert st["pages"] > 1 and st["page_retries"] == 0
     assert st["engine_calls"] == len(calls) == st["pages"]
+    assert st["h2d_arrays"] == 2 * len(calls)
     assert st["h2d_bytes"] == sum(_nbytes(a) for a, _, _ in calls)
     # a page copies every output the engine emits, the carry included
     assert st["d2h_bytes"] == sum(

@@ -63,8 +63,10 @@ def _shapes(args, sharding):
 
 
 def test_scan_engine_compiles_for_v5e(one_chip, cache_off, monkeypatch):
-    """The ``scan`` engine at a Fig-4 shape family (matrix app, spt/hcf
-    x 5 deadlines, 3 providers, J = 4096) compiles under x64."""
+    """The program the chip runs for the ``scan`` engine at a Fig-4 shape
+    family (matrix app, spt/hcf x 5 deadlines, 3 providers, J = 4096):
+    the packed arguments, cut back and fed to vmap(run_one), compile
+    under x64."""
     # the engine's backend-aware choices (inner-loop impl, prefix sums)
     # read jax.default_backend(); steer them to their TPU branch
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -73,12 +75,19 @@ def test_scan_engine_compiles_for_v5e(one_chip, cache_off, monkeypatch):
     class Caught(Exception):
         pass
 
-    def capture(key, args, S, n_dev):
-        caught.update(key=key, args=args)
+    build = vectorsim._engine_fn.__wrapped__
+
+    def engine_fn(*key, **kw):
+        # a fresh build: a cached one may hold the CPU branches
+        caught["key"] = key
+        return build(*key, **kw)
+
+    def capture(fn, args, S, n_dev):
+        caught.update(fn=fn, args=args)
         raise Caught
 
-    # capture the static key and the args of the real call path
-    monkeypatch.setattr(vectorsim, "_engine_fn", lambda *key: key)
+    # capture the program and the args of the real call path
+    monkeypatch.setattr(vectorsim, "_engine_fn", engine_fn)
     monkeypatch.setattr(vectorsim, "_dispatch", capture)
     dag = APPS["matrix"]
     pred, act = workload(dag, J, seed=0)
@@ -91,10 +100,11 @@ def test_scan_engine_compiles_for_v5e(one_chip, cache_off, monkeypatch):
                         portfolio=demo_portfolio(3))
     key = caught["key"]
     assert key[2] == J and key[-2:] == (1, "scan")
-    run_one = vectorsim._build_engine(*key[:16], impl=key[17])
+    layout, bufs = vectorsim._pack(caught["args"])
+    assert len(bufs) == 2
     with jax.enable_x64(True):
-        compiled = jax.jit(jax.vmap(run_one)).lower(
-            *_shapes(caught["args"], one_chip)).compile()
+        compiled = caught["fn"].lower(
+            layout, *_shapes(bufs, one_chip)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
     # a v5e chip has 16 GB of HBM
